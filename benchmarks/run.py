@@ -1,20 +1,25 @@
 """Benchmark driver: one section per paper table/figure + the roofline report.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast]
-Prints CSV sections; results are cached under artifacts/bench/."""
+Prints CSV sections; results are cached under artifacts/bench/.  A section
+that raises is reported and the rest still run; the script then exits
+non-zero, naming the failed sections."""
 from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 
-def _section(title, fn):
+def _section(title, fn, failed: list[str]):
     print(f"\n{'=' * 72}\n{title}\n{'=' * 72}", flush=True)
     t0 = time.perf_counter()
     try:
         fn()
-    except Exception as e:  # noqa: BLE001
-        print(f"SECTION FAILED: {type(e).__name__}: {e}")
+    except Exception:  # noqa: BLE001 - one section's failure must not stop the rest
+        traceback.print_exc()
+        print(f"SECTION FAILED: {title}", flush=True)
+        failed.append(title)
     print(f"[section time: {time.perf_counter() - t0:.1f}s]", flush=True)
 
 
@@ -30,14 +35,25 @@ def main() -> None:
     )
 
     fast = "--fast" in sys.argv
-    _section("Table 1: base algorithms with/without SlowMo", bench_table1.main)
-    _section("Table 2: time per iteration + communication model", bench_table2.main)
+    failed: list[str] = []
+    sections = [
+        ("Table 1: base algorithms with/without SlowMo", bench_table1.main),
+        ("Table 2: time per iteration + communication model", bench_table2.main),
+    ]
     if not fast:
-        _section("Figure 3: effect of tau", bench_fig3_tau.main)
-        _section("Appendix B.3: alpha/beta sweep", bench_b3_alphabeta.main)
-        _section("Appendix B.4: buffer strategies", bench_b4_buffers.main)
-    _section("Section 6: SlowMo-noaverage", bench_sec6_noaverage.main)
-    _section("Roofline (dry-run artifacts)", bench_roofline.main)
+        sections += [
+            ("Figure 3: effect of tau", bench_fig3_tau.main),
+            ("Appendix B.3: alpha/beta sweep", bench_b3_alphabeta.main),
+            ("Appendix B.4: buffer strategies", bench_b4_buffers.main),
+        ]
+    sections += [
+        ("Section 6: SlowMo-noaverage", bench_sec6_noaverage.main),
+        ("Roofline (dry-run artifacts)", bench_roofline.main),
+    ]
+    for title, fn in sections:
+        _section(title, fn, failed)
+    if failed:
+        sys.exit(f"{len(failed)} benchmark section(s) failed: {'; '.join(failed)}")
 
 
 if __name__ == "__main__":

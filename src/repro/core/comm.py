@@ -9,7 +9,7 @@ run in two modes:
   the layout the rest of the repo (init, checkpoints, benchmarks) speaks.
 
 * ``MeshBackend`` ("mesh") — the lowered path: the round body runs inside
-  ``jax.experimental.shard_map`` with the worker axis sharded over one or
+  ``jax.shard_map`` with the worker axis sharded over one or
   more mesh axes.  The exact average becomes ``jax.lax.pmean`` (lowers to an
   ``all-reduce``), and gossip/topology rolls become ``jax.lax.ppermute``
   (lower to ``collective-permute``).  Leaves keep a leading *local* worker
@@ -86,7 +86,7 @@ from repro.kernels import topk_compress
 PyTree = Any
 
 
-def _sparse_payload(x, r, ratio, use_pallas):
+def _sparse_payload(x, r, ratio):
     """Per-slot top-k payload of the error-feedback signal.
 
     ``x`` is a (L, ...) boundary-delta leaf, ``r`` its residual (same
@@ -97,9 +97,7 @@ def _sparse_payload(x, r, ratio, use_pallas):
     """
     sig = x.astype(jnp.float32) + r
     L = sig.shape[0]
-    vals, idx, spec = topk_compress.sparsify_batch(
-        sig.reshape(L, -1), ratio, use_pallas=use_pallas
-    )
+    vals, idx, spec = topk_compress.sparsify_batch(sig.reshape(L, -1), ratio)
     blocks, be, _ = spec
     dense = topk_compress.reconstruct(vals, idx, be)
     new_resid = (sig.reshape(L, blocks, be) - dense).reshape(sig.shape)
@@ -250,7 +248,6 @@ class AxisBackend:
         ratio: float,
         dtype=None,
         mask=None,
-        use_pallas: bool = False,
     ) -> tuple[PyTree, PyTree]:
         """Compressed exact average with error feedback (DeMo-style top-k).
 
@@ -272,7 +269,7 @@ class AxisBackend:
         )
 
         def one(x, r):
-            vals, idx, spec, new_resid = _sparse_payload(x, r, ratio, use_pallas)
+            vals, idx, spec, new_resid = _sparse_payload(x, r, ratio)
             acc = vals.astype(dtype) if dtype is not None else vals
             if mask is not None:
                 acc = acc * mask.astype(acc.dtype).reshape(-1, 1, 1)
@@ -291,14 +288,13 @@ class AxisBackend:
         ratio: float,
         dtype=None,
         mask=None,
-        use_pallas: bool = False,
     ) -> tuple[PendingMean, PyTree]:
         """Sparse variant of ``worker_mean_start``: kick off the compressed
         average, return ``(handle, new_residual)``.  The residual update is
         immediate (it is local); only the mean is held for
         ``worker_mean_done``."""
         mean, new_resid = self.worker_mean_sparse(
-            tree, residual, ratio, dtype, mask=mask, use_pallas=use_pallas
+            tree, residual, ratio, dtype, mask=mask
         )
         return PendingMean(mean), new_resid
 
@@ -487,7 +483,6 @@ class MeshBackend:
         ratio: float,
         dtype=None,
         mask=None,
-        use_pallas: bool = False,
     ) -> tuple[PyTree, PyTree]:
         """Compressed exact average: all-gather the sparse payload instead
         of all-reducing the dense buffer.
@@ -513,7 +508,7 @@ class MeshBackend:
         )
 
         def one(x, r):
-            vals, idx, spec, new_resid = _sparse_payload(x, r, ratio, use_pallas)
+            vals, idx, spec, new_resid = _sparse_payload(x, r, ratio)
             acc = vals.astype(dtype) if dtype is not None else vals
             if mask is not None:
                 acc = acc * mask.astype(acc.dtype).reshape(-1, 1, 1)
@@ -534,7 +529,6 @@ class MeshBackend:
         ratio: float,
         dtype=None,
         mask=None,
-        use_pallas: bool = False,
     ) -> tuple[PendingMean, PyTree]:
         """Issue the sparse boundary gathers HERE, consume the mean later.
 
@@ -544,7 +538,7 @@ class MeshBackend:
         behind the inner steps.  The residual update is local and returned
         immediately."""
         mean, new_resid = self.worker_mean_sparse(
-            tree, residual, ratio, dtype, mask=mask, use_pallas=use_pallas
+            tree, residual, ratio, dtype, mask=mask
         )
         return PendingMean(mean), new_resid
 

@@ -280,12 +280,15 @@ class ShardedPackSpec:
     @staticmethod
     def _gather(x):
         """Replicate a committed device-sharded array before host-side
-        slicing: XLA:CPU's eager/SPMD partitioner mis-assembles slice +
-        concatenate chains that cross the shard boundaries of a committed
-        input (observed on jax 0.4.37 forced-host devices), and these
-        global<->tree conversions only run at init/checkpoint/eval
-        boundaries — never in the mapped round body — so the gather is off
-        the hot path.  Tracers and uncommitted arrays pass through."""
+        slicing.  Sliced eagerly, every slice that crosses the shard
+        boundaries of a committed input is its own SPMD program with
+        collectives; on jax 0.9.0's XLA:CPU forced-host devices several
+        such programs in flight at once can starve the in-process
+        collective rendezvous until it aborts the process.  One gather per
+        buffer makes every later slice local.  These global<->tree
+        conversions only run at init/checkpoint/eval boundaries — never in
+        the mapped round body — so the gather is off the hot path.  Tracers
+        and uncommitted arrays pass through."""
         if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
             sh = getattr(x, "sharding", None)
             if isinstance(sh, jax.sharding.NamedSharding) and not sh.is_fully_replicated:

@@ -451,7 +451,6 @@ def outer_update(
             cfg.compress_ratio,
             cfg.average_dtype,
             mask=mask,
-            use_pallas=cfg.use_pallas,
         )
         x_tau = jax.tree.map(
             lambda o, d: o + d, state.outer_params, mean_delta
@@ -544,7 +543,6 @@ def _outer_update_stale(
                 cfg.compress_ratio,
                 cfg.average_dtype,
                 mask=state.boundary_mask if cfg.masked_average else None,
-                use_pallas=cfg.use_pallas,
             )
         else:
             handle = backend.worker_mean_start(
@@ -738,7 +736,6 @@ def make_slowmo_round(
                     cfg.compress_ratio,
                     cfg.average_dtype,
                     mask=bmask,
-                    use_pallas=cfg.use_pallas,
                 )
             else:
                 pending = backend.worker_mean_start(

@@ -6,6 +6,11 @@ fixed random first-order Markov chain with temperature-controlled entropy.
 A model that learns the transition matrix reaches the chain's conditional
 entropy; the gap to it is the optimizable signal.
 
+The chain is LOW-RANK: the transition logits from token ``a`` to token ``b``
+are ``E_in[a] . E_out[b]`` with (V, rank) factors drawn from the seed, so the
+sampler holds O(V * rank) numbers instead of a dense (V, V) matrix — at a
+50k vocabulary one dense f32 matrix alone is 10 GB.
+
 Worker heterogeneity (the D_i in Eq. (1) of the paper): each worker draws
 from a worker-specific interpolation between the shared chain and a
 worker-local chain, controlled by ``heterogeneity`` in [0, 1].  This lets
@@ -14,10 +19,14 @@ experiments dial the inter-worker gradient discrepancy zeta^2 of Corollary 1.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+RANK = 64  # rank of the transition logits
+ENTROPY_MAX_VOCAB = 4096  # chain_entropy builds the dense (V, V) matrix
 
 
 @dataclasses.dataclass(frozen=True)
@@ -28,50 +37,78 @@ class MarkovLMConfig:
     seed: int = 0
 
 
-def _transition_logits(key, vocab: int) -> jnp.ndarray:
-    return jax.random.normal(key, (vocab, vocab))
+def _chain_factors(key, vocab: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(E_in, E_out) of one chain, scaled so each logit has unit variance."""
+    rank = min(RANK, vocab)
+    k_in, k_out = jax.random.split(key)
+    e_in = jax.random.normal(k_in, (vocab, rank)) * rank**-0.5
+    return e_in, jax.random.normal(k_out, (vocab, rank))
 
 
 def make_markov_sampler(cfg: MarkovLMConfig, num_workers: int):
     """Returns sample(step, tau, per_worker_batch, seq) -> (tau, W, B, S) int32."""
     base_key = jax.random.PRNGKey(cfg.seed)
-    shared = _transition_logits(jax.random.fold_in(base_key, 1), cfg.vocab_size)
-    local = jnp.stack(
-        [
-            _transition_logits(jax.random.fold_in(base_key, 100 + w), cfg.vocab_size)
+    e_in, e_out = _chain_factors(jax.random.fold_in(base_key, 1), cfg.vocab_size)
+    h = cfg.heterogeneity
+    if h:
+        # mixed logits (1-h) * shared + h * local, as one rank-2r chain per
+        # worker: (W, V, 2r) factors
+        local = [
+            _chain_factors(jax.random.fold_in(base_key, 100 + w), cfg.vocab_size)
             for w in range(num_workers)
         ]
-    )
-    mix = (1 - cfg.heterogeneity) * shared[None] + cfg.heterogeneity * local
-    probs = jax.nn.softmax(mix / cfg.temperature, axis=-1)  # (W, V, V)
+        e_in = jnp.stack(
+            [jnp.concatenate([(1 - h) * e_in, h * li], -1) for li, _ in local]
+        )
+        e_out = jnp.stack([jnp.concatenate([e_out, lo], -1) for _, lo in local])
+    else:
+        e_in, e_out = e_in[None], e_out[None]
+    e_in = e_in / cfg.temperature
+    # one factor set shared by every worker, or one per worker
+    wsel = jnp.arange(num_workers) if h else jnp.zeros(num_workers, jnp.int32)
 
-    import functools
-
-    @functools.partial(jax.jit, static_argnums=(1, 2, 3))
-    def sample(step: int, tau: int, batch: int, seq: int):
+    # the factors are ARGUMENTS, not constants baked into the program
+    @functools.partial(jax.jit, static_argnums=(3, 4, 5))
+    def _sample(e_in, e_out, step, tau: int, batch: int, seq: int):
         key = jax.random.fold_in(jax.random.fold_in(base_key, 7), step)
         k0, kseq = jax.random.split(key)
         shape = (tau, num_workers, batch)
         first = jax.random.randint(k0, shape, 0, cfg.vocab_size)
+        w = wsel[None, :, None]
+        out_w = e_out[wsel]  # (W, V, r)
 
         def body(tok, k):
-            # tok: (tau, W, B); per-worker transition row lookup
-            p = probs[jnp.arange(num_workers)[None, :, None], tok]  # (tau,W,B,V)
-            nxt = jax.random.categorical(k, jnp.log(p + 1e-9))
+            emb = e_in[w, tok]  # (tau, W, B, r)
+            logits = jnp.einsum("twbr,wvr->twbv", emb, out_w)
+            nxt = jax.random.categorical(k, logits)
             return nxt, nxt
 
         _, toks = jax.lax.scan(body, first, jax.random.split(kseq, seq - 1))
         toks = jnp.concatenate([first[None], toks], axis=0)  # (S, tau, W, B)
         return jnp.transpose(toks, (1, 2, 3, 0)).astype(jnp.int32)
 
+    def sample(step: int, tau: int, batch: int, seq: int):
+        return _sample(e_in, e_out, step, tau, batch, seq)
+
     return sample
 
 
 def chain_entropy(cfg: MarkovLMConfig) -> float:
-    """Stationary conditional entropy of the *shared* chain (loss floor, nats)."""
+    """Stationary conditional entropy of the *shared* chain (loss floor, nats).
+
+    Builds the dense (V, V) transition matrix on the host, so it is limited
+    to vocabularies up to ``ENTROPY_MAX_VOCAB``."""
+    if cfg.vocab_size > ENTROPY_MAX_VOCAB:
+        raise ValueError(
+            f"chain_entropy builds a dense (V, V) matrix; vocab_size="
+            f"{cfg.vocab_size} exceeds {ENTROPY_MAX_VOCAB}"
+        )
     key = jax.random.PRNGKey(cfg.seed)
-    logits = np.asarray(_transition_logits(jax.random.fold_in(key, 1), cfg.vocab_size))
-    P = np.asarray(jax.nn.softmax(jnp.asarray(logits) / cfg.temperature, axis=-1))
+    e_in, e_out = _chain_factors(jax.random.fold_in(key, 1), cfg.vocab_size)
+    logits = np.asarray(e_in, np.float64) @ np.asarray(e_out, np.float64).T
+    logits = logits / cfg.temperature
+    P = np.exp(logits - logits.max(-1, keepdims=True))
+    P /= P.sum(-1, keepdims=True)
     # stationary distribution via power iteration
     pi = np.ones(cfg.vocab_size) / cfg.vocab_size
     for _ in range(200):
@@ -89,8 +126,6 @@ def make_audio_sampler(vocab: int, frontend_dim: int, num_workers: int, seed: in
     """
     key = jax.random.PRNGKey(seed)
     codebook = jax.random.normal(jax.random.fold_in(key, 1), (frontend_dim, vocab))
-
-    import functools
 
     @functools.partial(jax.jit, static_argnums=(1, 2, 3))
     def sample(step: int, tau: int, batch: int, seq: int):

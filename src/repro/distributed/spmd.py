@@ -1,4 +1,4 @@
-"""Mesh-lowered SlowMo execution: the round under ``jax.experimental.shard_map``.
+"""Mesh-lowered SlowMo execution: the round under ``jax.shard_map``.
 
 This is the path that turns the array-axis *simulation* of m workers into a
 distributable SPMD program.  ``make_spmd_slowmo_round`` takes the same
@@ -83,7 +83,6 @@ from typing import Any, Callable
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import comm, packing, slowmo
@@ -257,12 +256,12 @@ def build_spmd_round(
         # the (W,) participation mask is a fourth traced input, sharded over
         # the worker axes — masks change per round without recompiling
         in_specs = in_specs + (sharding.spmd_mask_spec(layout),)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=layout.mesh,
         in_specs=in_specs,
         out_specs=(state_specs, metric_specs),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped, donate_argnums=0)
 
@@ -402,12 +401,12 @@ def make_paged_serve_step(
         )
         return sampled, k_pages, v_pages
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=layout.mesh,
         in_specs=(param_specs, pool_spec, pool_spec, P(), P(), P(), P(), P()),
         out_specs=(P(), pool_spec, pool_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(mapped, donate_argnums=(1, 2))
 

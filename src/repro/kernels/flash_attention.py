@@ -2,8 +2,11 @@
 
 Grid = (batch, q_heads, q_blocks, kv_blocks); the kv_blocks axis is the
 innermost (sequential on TPU), so the running softmax statistics live in VMEM
-scratch across kv iterations.  BlockSpecs stream (block_q x D) query tiles and
-(block_k x D) key/value tiles through VMEM; with the default 128x128 blocks
+scratch across kv iterations.  The kernel sees head-major (B, H, S, D)
+arrays, so every block is a (block_q x D) or (block_k x D) tile whose two
+minor dims are the sequence and the head dim (Mosaic tiles the last two
+dims of a block; a (1, block, 1, D) block over (B, S, H, D) is refused).
+BlockSpecs stream these tiles through VMEM; with the default 128x128 blocks
 and D<=128 the working set is ~0.5 MiB — far under VMEM, leaving room for XLA
 to overlap DMA with MXU work.  GQA is expressed in the k/v index_map
 (``h // group``), so kv tiles are fetched once per kv head, not per q head
@@ -70,9 +73,9 @@ def _kernel(
 
     @pl.when(should_run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale
+        k = k_ref[...].astype(jnp.float32)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (block_q, block_k)
@@ -102,7 +105,7 @@ def _kernel(
     def _finalize():
         l = l_scr[:, 0]
         l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -129,18 +132,18 @@ def flash_attention(
     block_k = min(block_k, max(8, Skv))
     pad_q = (-Sq) % block_q
     pad_k = (-Skv) % block_k
-    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))) if pad_q else q
-    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else k
-    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))) if pad_k else v
+    # head-major for the kernel: (B, S, H, D) -> (B, H, S_padded, D)
+    qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    kp = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     Sq_p, Skv_p = Sq + pad_q, Skv + pad_k
     nq, nk = Sq_p // block_q, Skv_p // block_k
 
     grid = (B, Hq, nq, nk)
-    q_spec = pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0))
+    q_spec = pl.BlockSpec((None, None, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec(
-        (1, block_k, 1, D), lambda b, h, i, j: (b, j, h // group, 0)
+        (None, None, block_k, D), lambda b, h, i, j: (b, h // group, j, 0)
     )
-    o_spec = pl.BlockSpec((1, block_q, 1, D), lambda b, h, i, j: (b, i, h, 0))
 
     kernel = functools.partial(
         _kernel,
@@ -157,8 +160,8 @@ def flash_attention(
         kernel,
         grid=grid,
         in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, Hq, D), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq_p, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, STATS_LANES), jnp.float32),
@@ -166,4 +169,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:, :Sq] if pad_q else out
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: per-block magnitude top-k for boundary compression.
+"""Per-block magnitude top-k for boundary compression.
 
 DeMo-style sparsification of the SlowMo boundary signal (PAPERS.md,
 arXiv 2411.19870 / 2510.03371): each worker transmits only the k
@@ -6,8 +6,8 @@ largest-magnitude entries of its boundary delta (plus the error-feedback
 residual), as a statically-shaped (values, indices) payload, and the
 untransmitted remainder is carried forward locally.
 
-The payload layout is deterministic and shared by the kernel, the jnp
-oracle, and the collective contract (``analysis/contract.py``):
+The payload layout is deterministic and shared by the selection and the
+collective contract (``analysis/contract.py``):
 
 * a signal of n elements splits into fixed blocks via ``payload_spec`` —
   ``BLOCK_ELEMS``-sized blocks when n is a multiple of ``BLOCK_ELEMS``
@@ -23,26 +23,17 @@ Per-block k keeps every payload statically shaped, so the all-gather that
 replaces the dense boundary all-reduce (``comm.worker_mean_sparse``) has a
 fixed HLO census the contract can budget.
 
-The kernel mirrors ``slowmo_update.py``: grid over 64-row tiles of a
-(rows, 1024) f32 buffer, one ``jax.lax.top_k`` per tile over the flattened
-block in VMEM (64 * 1024 * 4 B = 256 KiB per input tile).  Off-TPU it runs
-in interpret mode; non-aligned (tree-layout) leaves use the jnp oracle.
+The selection is XLA's ``jax.lax.top_k`` on every backend: Mosaic (the
+Pallas TPU compiler) has no lowering for ``top_k``, so there is no kernel.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 LANES = 1024
 BLOCK_ROWS = 64
 BLOCK_ELEMS = BLOCK_ROWS * LANES  # 65536 elements per top-k block
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def payload_spec(n: int, ratio: float) -> tuple[int, int, int]:
@@ -68,9 +59,8 @@ def sparsify_ref(flat: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Magnitude top-k of a (..., block_elems) signal; pure-jnp oracle.
 
     Returns ``(values, indices)`` of shape (..., k) — f32 signed values and
-    s32 positions within each block.  The numerical reference for the
-    Pallas path (identical selection; ``jax.lax.top_k`` tie-breaking by
-    lowest index in both).
+    s32 positions within each block (``jax.lax.top_k`` breaks ties by
+    lowest index).
     """
     _, idx = jax.lax.top_k(jnp.abs(flat), k)
     idx = idx.astype(jnp.int32)
@@ -94,67 +84,19 @@ def reconstruct(vals: jax.Array, idx: jax.Array, block_elems: int) -> jax.Array:
     return fn(vals, idx)
 
 
-def _topk_kernel(x_ref, v_ref, i_ref, *, k):
-    x = x_ref[...].reshape(-1)
-    _, idx = jax.lax.top_k(jnp.abs(x), k)
-    idx = idx.astype(jnp.int32)
-    v_ref[...] = jnp.take(x, idx).astype(jnp.float32).reshape(1, k)
-    i_ref[...] = idx.reshape(1, k)
-
-
-def topk_2d(
-    x: jax.Array,
-    k: int,
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Per-64-row-block magnitude top-k of a (rows, LANES) f32 buffer.
-
-    Returns ``(values, indices)`` of shape (rows // 64, k).  Block b covers
-    rows [64b, 64(b+1)) flattened row-major — the same element order as
-    ``sparsify_ref`` on the row-major flattening, so the two paths produce
-    identical payloads.
-    """
-    rows, lanes = x.shape
-    assert lanes == LANES and rows % BLOCK_ROWS == 0, (x.shape,)
-    blocks = rows // BLOCK_ROWS
-    out_blk = pl.BlockSpec((1, k), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_topk_kernel, k=k),
-        grid=(blocks,),
-        in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0))],
-        out_specs=[out_blk, out_blk],
-        out_shape=[
-            jax.ShapeDtypeStruct((blocks, k), jnp.float32),
-            jax.ShapeDtypeStruct((blocks, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(x)
-
-
 def sparsify_batch(
-    x: jax.Array,
-    ratio: float,
-    *,
-    use_pallas: bool = False,
-    interpret: bool | None = None,
+    x: jax.Array, ratio: float
 ) -> tuple[jax.Array, jax.Array, tuple[int, int, int]]:
     """Per-slot magnitude top-k of a batched signal.
 
     ``x`` is (L, ...) — one independent signal per leading slot (the local
     worker axis).  Returns ``(values, indices, spec)`` with payloads of
     shape (L, num_blocks, k) and ``spec = payload_spec(per-slot n, ratio)``.
-    The Pallas kernel handles BLOCK_ELEMS-aligned signals (the packed flat
-    buffers); everything else takes the jnp oracle.
     """
     L = x.shape[0]
     n = x.size // L
     spec = payload_spec(n, ratio)
     blocks, be, k = spec
     flat = x.reshape(L, n).astype(jnp.float32)
-    if use_pallas and be == BLOCK_ELEMS:
-        interp = _interpret() if interpret is None else interpret
-        vals, idx = topk_2d(flat.reshape(-1, LANES), k, interpret=interp)
-    else:
-        vals, idx = sparsify_ref(flat.reshape(L * blocks, be), k)
+    vals, idx = sparsify_ref(flat.reshape(L * blocks, be), k)
     return vals.reshape(L, blocks, k), idx.reshape(L, blocks, k), spec

@@ -23,21 +23,36 @@ import dataclasses
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
+
+
+def device_grid(shape, what: str = "mesh") -> np.ndarray:
+    """The first ``prod(shape)`` devices arranged as ``shape``.
+
+    A grid over every device follows the interconnect
+    (``mesh_utils.create_device_mesh``: on a TPU slice neighbouring mesh
+    coordinates are neighbouring chips, which ``jax.devices()`` list order
+    does not promise); a grid over a subset keeps list order.
+    """
+    shape = tuple(int(d) for d in shape)
+    n = int(np.prod(shape))
+    devs = jax.devices()
+    if len(devs) < n:
+        raise ValueError(f"need {n} devices for a {what}, have {len(devs)}")
+    if n == len(devs):
+        return mesh_utils.create_device_mesh(shape, devices=devs)
+    return np.asarray(devs[:n]).reshape(shape)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(devices, axes)
+    return Mesh(device_grid(shape), axes)
 
 
 def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")) -> Mesh:
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(devices, axes)
+    return Mesh(device_grid(shape), axes)
 
 
 def make_worker_mesh(num_workers: int, axis: str = "data") -> Mesh:
@@ -48,12 +63,7 @@ def make_worker_mesh(num_workers: int, axis: str = "data") -> Mesh:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=<num_workers>``
     before the first jax import.
     """
-    devs = jax.devices()
-    if len(devs) < num_workers:
-        raise ValueError(
-            f"need {num_workers} devices for a worker mesh, have {len(devs)}"
-        )
-    return Mesh(np.asarray(devs[:num_workers]), (axis,))
+    return Mesh(device_grid((num_workers,), "worker mesh"), (axis,))
 
 
 def make_spmd_layout(num_workers: int, tp: int = 1) -> WorkerLayout:
@@ -65,15 +75,8 @@ def make_spmd_layout(num_workers: int, tp: int = 1) -> WorkerLayout:
     if tp <= 1:
         mesh = make_worker_mesh(num_workers)
         return WorkerLayout(mesh, worker_axes=("data",), batch_axes=(), model_axes=())
-    n = num_workers * tp
-    devs = jax.devices()
-    if len(devs) < n:
-        raise ValueError(
-            f"need {n} devices for a ({num_workers} data x {tp} model) mesh, "
-            f"have {len(devs)}"
-        )
-    mesh = Mesh(np.asarray(devs[:n]).reshape(num_workers, tp), ("data", "model"))
-    return make_layout(mesh, "flat", spmd=True)
+    grid = device_grid((num_workers, tp), f"({num_workers} data x {tp} model) mesh")
+    return make_layout(Mesh(grid, ("data", "model")), "flat", spmd=True)
 
 
 def make_hierarchical_layout(pods: int, data: int, tp: int = 1) -> WorkerLayout:
@@ -90,19 +93,11 @@ def make_hierarchical_layout(pods: int, data: int, tp: int = 1) -> WorkerLayout:
     CPU-only host set ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
     before the first jax import.
     """
-    n = pods * data * tp
-    devs = jax.devices()
-    if len(devs) < n:
-        raise ValueError(
-            f"need {n} devices for a ({pods} pods x {data} data"
-            f"{f' x {tp} model' if tp > 1 else ''}) mesh, have {len(devs)}"
-        )
+    what = f"({pods} pods x {data} data{f' x {tp} model' if tp > 1 else ''}) mesh"
     if tp <= 1:
-        mesh = Mesh(np.asarray(devs[:n]).reshape(pods, data), ("pod", "data"))
+        mesh = Mesh(device_grid((pods, data), what), ("pod", "data"))
     else:
-        mesh = Mesh(
-            np.asarray(devs[:n]).reshape(pods, data, tp), ("pod", "data", "model")
-        )
+        mesh = Mesh(device_grid((pods, data, tp), what), ("pod", "data", "model"))
     return make_layout(mesh, "hierarchical", spmd=True)
 
 

@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..configs import ARCH_IDS, get_config
@@ -34,6 +35,7 @@ from ..serve import (
     ServeConfig,
 )
 from ..train import checkpoint
+from .compile_cache import enable_compile_cache
 
 
 def _run_static(args, cfg, model, params):
@@ -53,7 +55,10 @@ def _run_static(args, cfg, model, params):
           f"end-to-end {stats['tokens_per_s']:.1f} tok/s")
 
 
-def _run_continuous(args, cfg, model, params):
+def run_continuous(args, cfg, model, params):
+    """Serve ``args.requests`` synthetic requests through the continuous
+    engine; prints the engine's stats and returns ``(results, stats)`` as
+    ``ContinuousEngine.run`` does."""
     layout = None
     if args.tp > 1:
         from ..launch.mesh import make_spmd_layout
@@ -82,15 +87,16 @@ def _run_continuous(args, cfg, model, params):
         )
         for i in range(args.requests)
     ]
-    _, stats = engine.run(reqs)
+    results, stats = engine.run(reqs)
     print(f"{stats['num_requests']} requests in {stats['steps']} steps | "
           f"{stats['tokens_per_s']:.1f} tok/s | "
           f"latency p50 {stats['latency_p50']*1e3:.1f} ms "
           f"p99 {stats['latency_p99']*1e3:.1f} ms | "
           f"ttft p50 {stats['ttft_p50']*1e3:.1f} ms")
+    return results, stats
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=ARCH_IDS)
     ap.add_argument("--batch", type=int, default=4)
@@ -109,20 +115,33 @@ def main():
     ap.add_argument("--num-pages", type=int, default=128)
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree (--continuous only)")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch, reduced=not args.full)
-    model = build_model(cfg)
-    if model.decode_step is None:
-        raise SystemExit(f"{args.arch} is encoder-only: no decode path")
+
+def load_params(args, cfg, model):
+    """Checkpointed or seeded params; a --full config holds its weights in
+    its compute dtype (bf16 for the published configs)."""
     if args.ckpt and checkpoint.exists(args.ckpt):
         params, _ = checkpoint.restore(args.ckpt)
     else:
         params = model.init(jax.random.PRNGKey(0))
+    if args.full:
+        params = jax.tree.map(lambda x: jnp.asarray(x, cfg.dtype), params)
+    return params
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = get_config(args.arch, reduced=not args.full)
+    model = build_model(cfg)
+    if model.decode_step is None:
+        raise SystemExit(f"{args.arch} is encoder-only: no decode path")
+    params = load_params(args, cfg, model)
     print(f"{args.arch}: {param_count(params)/1e6:.1f}M params")
 
     if args.continuous:
-        _run_continuous(args, cfg, model, params)
+        run_continuous(args, cfg, model, params)
     else:
         if args.tp > 1:
             raise SystemExit("--tp requires --continuous (the paged TP step)")

@@ -1,8 +1,9 @@
 """Training launcher: pick an architecture + SlowMo algorithm and train.
 
-On the CPU container this runs REDUCED configs (full configs are exercised by
-dryrun.py); on a real TPU slice the same entry point drives the full configs
-with the production mesh sharding.
+By default it runs the REDUCED configs; ``--full`` takes the published
+widths, and ``--layers N`` cuts a full config's depth to what the chips
+hold.  On a TPU the fused Pallas kernels (lines 7-8, the Nesterov inner
+step) run compiled; elsewhere the same math runs as XLA elementwise ops.
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --algo sgp+slowmo \
         --rounds 20 --workers 8 --tau 12
@@ -10,6 +11,7 @@ with the production mesh sharding.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -20,9 +22,10 @@ from ..data import MarkovLMConfig, make_audio_sampler, make_markov_sampler
 from ..models import build_model, param_count
 from ..train import TrainConfig, Trainer
 from ..train import checkpoint as ckpt_lib
+from .compile_cache import enable_compile_cache
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b", choices=ARCH_IDS)
     ap.add_argument("--algo", default="local_sgd+slowmo")
@@ -40,6 +43,13 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--full", action="store_true", help="full-size config (TPU)")
+    ap.add_argument(
+        "--layers",
+        type=int,
+        default=None,
+        help="with --full: keep the published widths and cut the depth to "
+        "this many layers",
+    )
     ap.add_argument(
         "--packed",
         action="store_true",
@@ -131,8 +141,12 @@ def main():
         default=1,
         help="elastic: abort rather than evict below this many survivors",
     )
-    args = ap.parse_args()
+    return ap
 
+
+def build_trainer(args) -> Trainer:
+    """The Trainer the parsed launcher ``args`` describe (mesh, model, data,
+    SlowMo config); ``main`` runs it."""
     if args.tp > 1 and args.mesh != "host":
         raise SystemExit("--tp needs --mesh host (tensor parallelism is a mesh-path feature)")
 
@@ -157,9 +171,14 @@ def main():
         print(f"mesh path ({args.layout}): {args.workers} workers over {layout.mesh}")
 
     cfg = get_config(args.arch, reduced=not args.full)
+    if args.layers is not None:
+        if not args.full:
+            raise SystemExit("--layers cuts a --full config's depth")
+        cfg = cfg.replace(n_layers=args.layers)
     model = build_model(cfg)
     n = param_count(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    print(f"{args.arch}{'' if args.full else ' (reduced)'}: {n/1e6:.1f}M params")
+    size = f"{cfg.n_layers} layers" if args.full else "reduced"
+    print(f"{args.arch} ({size}): {n/1e6:.1f}M params")
 
     if cfg.modality == "audio":
         sampler = make_audio_sampler(cfg.vocab_size, cfg.frontend_dim, args.workers)
@@ -167,12 +186,11 @@ def main():
         data = MarkovLMConfig(vocab_size=cfg.vocab_size, temperature=0.8)
         sampler = make_markov_sampler(data, args.workers)
 
-    import dataclasses
-
     smcfg = dataclasses.replace(
         slowmo.preset(args.algo, num_workers=args.workers, tau=args.tau, beta=args.beta),
         alpha=args.alpha,
         param_dtype=cfg.dtype if args.full else jnp.float32,
+        use_pallas=jax.default_backend() == "tpu",
         packed=args.packed,
         overlap_boundary=args.overlap_boundary,
         compress_ratio=args.compress_ratio,
@@ -201,9 +219,15 @@ def main():
         if faults:
             print(f"elastic: injecting {len(faults.events)} fault(s)")
 
-    trainer = Trainer(
+    return Trainer(
         model, smcfg, tc, sampler, layout=layout, elastic=elastic, faults=faults
     )
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    trainer = build_trainer(args)
 
     state = None
     if args.ckpt and ckpt_lib.exists(args.ckpt):

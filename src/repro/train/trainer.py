@@ -152,8 +152,23 @@ class Trainer:
         )
 
     def init_state(self, key=None) -> SlowMoState:
-        params = self.model.init(key or jax.random.PRNGKey(0))
-        return slowmo.init_slowmo(self.smcfg, params, pack=self.pack)
+        key = jax.random.PRNGKey(0) if key is None else key
+
+        def init(key):
+            return slowmo.init_slowmo(self.smcfg, self.model.init(key), pack=self.pack)
+
+        # one compiled init on every path, so a mesh run and the array-axis
+        # oracle start from the same floats; on a mesh each device builds its
+        # shard in place (the global state holds W copies of the worker
+        # state, more than one chip's memory)
+        shardings = None
+        if self.layout is not None:
+            from ..distributed import spmd
+
+            shardings = spmd.state_shardings(
+                self.smcfg, self.layout, jax.eval_shape(init, key)
+            )
+        return jax.jit(init, out_shardings=shardings)(key)
 
     def _batches(self, round_idx: int) -> PyTree:
         raw = self.sampler(

