@@ -129,6 +129,7 @@ class PackSpec:
                 )
         return lead
 
+    @jax.named_scope("layout")
     def pack(self, tree: PyTree, dtype=None) -> Packed:
         """Pack ``tree`` into flat buffers shaped ``lead + (rows, LANES)``.
 
@@ -160,6 +161,7 @@ class PackSpec:
             buffers[group] = buf.reshape(lead + (rows, LANES))
         return Packed(buffers)
 
+    @jax.named_scope("layout")
     def unpack(self, packed: Packed, dtype=None) -> PyTree:
         """Recover the pytree; leaves keep the buffer's storage dtype unless
         ``dtype`` is given.  Slices + reshapes only — no arithmetic."""
@@ -343,6 +345,7 @@ class ShardedPackSpec:
                 )
         return jax.tree.unflatten(self.shard.treedef, out)
 
+    @jax.named_scope("layout")
     def pack(self, tree: PyTree, dtype=None) -> Packed:
         """Full tree -> global shard-major buffers ``lead + (S*rows, LANES)``."""
         # gather committed sharded leaves ONCE, not once per shard block
@@ -360,6 +363,7 @@ class ShardedPackSpec:
             }
         )
 
+    @jax.named_scope("layout")
     def unpack(self, packed: Packed, dtype=None) -> PyTree:
         """Global shard-major buffers -> the full tree (concat over shards)."""
         packed = Packed({g: self._gather(v) for g, v in packed.buffers.items()})

@@ -345,41 +345,46 @@ def make_inner_step(
         else:
             z = params
         z_tree = pack.unpack(z) if pack is not None else z
-        losses, grads = vgrad(z_tree, batch)
+        with jax.named_scope("fwd_bwd"):
+            losses, grads = vgrad(z_tree, batch)
         if pack is not None:
             grads = pack.pack(grads, dtype=jnp.float32)
-        if cfg.base == "ar":
-            # ALLREDUCE baseline: average gradients across workers every
-            # step.  mean_keepdims reduces over worker AND batch axes in one
-            # collective, so this subsumes the hierarchical within-pod sync.
-            grads = jax.tree.map(backend.mean_keepdims, grads)
-        elif grad_pack is not None and backend.batch_axes:
-            # tree-carry on a hierarchical backend: pack the gradients just
-            # for the within-pod sync (ONE collective per buffer) and unpack
-            # the reduced result back into the cached tree layout.
-            grads = grad_pack.unpack(
-                backend.grad_mean(grad_pack.pack(grads, dtype=jnp.float32))
+        with jax.named_scope("grad_sync"):
+            if cfg.base == "ar":
+                # ALLREDUCE baseline: average gradients across workers every
+                # step.  mean_keepdims reduces over worker AND batch axes in
+                # one collective, so this subsumes the hierarchical within-pod
+                # sync.
+                grads = jax.tree.map(backend.mean_keepdims, grads)
+            elif grad_pack is not None and backend.batch_axes:
+                # tree-carry on a hierarchical backend: pack the gradients
+                # just for the within-pod sync (ONE collective per buffer) and
+                # unpack the reduced result back into the cached tree layout.
+                grads = grad_pack.unpack(
+                    backend.grad_mean(grad_pack.pack(grads, dtype=jnp.float32))
+                )
+            else:
+                # Hierarchical layouts: within-pod DP sync — all-reduce the
+                # gradients over the backend's batch axes so every device in
+                # a pod steps with the gradient of the full pod batch
+                # (identity on the oracle and on layouts without batch axes).
+                # Runs AFTER packing (one collective on packed state) and
+                # BEFORE clipping/momentum inside apply_step, so the inner
+                # optimizer sees exactly the bigger-batch worker's gradient.
+                grads = backend.grad_mean(grads)
+        with jax.named_scope("inner_opt"):
+            params, inner = base_opt.apply_step(
+                cfg.inner,
+                inner,
+                params,
+                grads,
+                lr,
+                z=z if gcfg.kind in ("sgp", "osgp") else None,
+                use_pallas=cfg.use_pallas,
+                sq_fn=sq_fn,
             )
-        else:
-            # Hierarchical layouts: within-pod DP sync — all-reduce the
-            # gradients over the backend's batch axes so every device in a
-            # pod steps with the gradient of the full pod batch (identity on
-            # the oracle and on layouts without batch axes).  Runs AFTER
-            # packing (one collective on packed state) and BEFORE clipping/
-            # momentum inside apply_step, so the inner optimizer sees exactly
-            # the bigger-batch worker's gradient.
-            grads = backend.grad_mean(grads)
-        params, inner = base_opt.apply_step(
-            cfg.inner,
-            inner,
-            params,
-            grads,
-            lr,
-            z=z if gcfg.kind in ("sgp", "osgp") else None,
-            use_pallas=cfg.use_pallas,
-            sq_fn=sq_fn,
-        )
-        params, gstate = gossip.mix(gcfg, gstate, params, step, backend)
+        with jax.named_scope("gossip"):
+            params, gstate = gossip.mix(gcfg, gstate, params, step, backend)
         loss = backend.pmean_scalar(jnp.mean(losses))
         return (params, inner, gstate, step + 1), loss
 
@@ -395,6 +400,45 @@ def _debias_endpoint(cfg: SlowMoConfig, state: SlowMoState) -> PyTree:
     return state.params
 
 
+def _line6(cfg: SlowMoConfig, state: SlowMoState, backend, mask):
+    """Line 6 of Algorithm 1: the averaged endpoint ``x_tau`` the slow
+    momentum steps toward, and the error-feedback residual after it."""
+    if cfg.exact_average and cfg.compress_ratio is not None:
+        # Compressed line 6: average the top-k payload of each worker's
+        # DELTA against the shared outer anchor (plus its error-feedback
+        # residual), then rebuild x_tau = anchor + mean(sparse delta).
+        # Compressing the delta, not the iterate, is what makes top-k
+        # meaningful — the delta is the tau-step movement, small and
+        # concentrated, while the iterate's energy is everywhere.
+        delta = jax.tree.map(
+            lambda e, o: e.astype(jnp.float32) - o[None],
+            _debias_endpoint(cfg, state),
+            state.outer_params,
+        )
+        mean_delta, new_resid = backend.worker_mean_sparse(
+            delta,
+            state.residual,
+            cfg.compress_ratio,
+            cfg.average_dtype,
+            mask=mask,
+        )
+        x_tau = jax.tree.map(lambda o, d: o + d, state.outer_params, mean_delta)
+        return x_tau, new_resid
+    if cfg.exact_average:
+        # Line 6: exact average over the worker axis -> all-reduce.
+        x_tau = backend.worker_mean(
+            _debias_endpoint(cfg, state), cfg.average_dtype, mask=mask
+        )
+    else:
+        # noaverage (§6): skip line 6; each worker applies the slow update
+        # to its own drift (outer state carries the worker axis).
+        x_tau = jax.tree.map(
+            lambda x: x.astype(jnp.float32), _debias_endpoint(cfg, state)
+        )
+    return x_tau, state.residual
+
+
+@jax.named_scope("boundary")
 def outer_update(
     cfg: SlowMoConfig,
     state: SlowMoState,
@@ -432,59 +476,18 @@ def outer_update(
     backend = backend or comm.AxisBackend(cfg.num_workers)
     if cfg.overlap_boundary:
         return _outer_update_stale(cfg, state, lr, backend, mask, stale_handle, kops)
-    new_resid = state.residual
-    if cfg.exact_average and cfg.compress_ratio is not None:
-        # Compressed line 6: average the top-k payload of each worker's
-        # DELTA against the shared outer anchor (plus its error-feedback
-        # residual), then rebuild x_tau = anchor + mean(sparse delta).
-        # Compressing the delta, not the iterate, is what makes top-k
-        # meaningful — the delta is the tau-step movement, small and
-        # concentrated, while the iterate's energy is everywhere.
-        delta = jax.tree.map(
-            lambda e, o: e.astype(jnp.float32) - o[None],
-            _debias_endpoint(cfg, state),
+    with jax.named_scope("line6"):
+        x_tau, new_resid = _line6(cfg, state, backend, mask)
+    with jax.named_scope("lines7_8"):
+        new_outer, new_u = kops.slowmo_outer_update(
             state.outer_params,
+            x_tau,
+            state.slow_u,
+            gamma=lr,
+            alpha=cfg.alpha,
+            beta=cfg.beta,
+            use_pallas=cfg.use_pallas,
         )
-        mean_delta, new_resid = backend.worker_mean_sparse(
-            delta,
-            state.residual,
-            cfg.compress_ratio,
-            cfg.average_dtype,
-            mask=mask,
-        )
-        x_tau = jax.tree.map(
-            lambda o, d: o + d, state.outer_params, mean_delta
-        )
-    elif cfg.exact_average:
-        # Line 6: exact average over the worker axis -> all-reduce.
-        if cfg.gossip_config.kind in ("sgp", "osgp"):
-            x_tau = backend.worker_mean(
-                gossip.debias(state.params, state.gossip.w),
-                cfg.average_dtype,
-                mask=mask,
-            )
-        else:
-            x_tau = backend.worker_mean(state.params, cfg.average_dtype, mask=mask)
-    else:
-        # noaverage (§6): skip line 6; each worker applies the slow update
-        # to its own drift (outer state carries the worker axis).
-        if cfg.gossip_config.kind in ("sgp", "osgp"):
-            x_tau = jax.tree.map(
-                lambda x: x.astype(jnp.float32),
-                gossip.debias(state.params, state.gossip.w),
-            )
-        else:
-            x_tau = jax.tree.map(lambda x: x.astype(jnp.float32), state.params)
-
-    new_outer, new_u = kops.slowmo_outer_update(
-        state.outer_params,
-        x_tau,
-        state.slow_u,
-        gamma=lr,
-        alpha=cfg.alpha,
-        beta=cfg.beta,
-        use_pallas=cfg.use_pallas,
-    )
 
     if cfg.exact_average:
         new_params = backend.bcast(new_outer, cfg.param_dtype)
@@ -533,51 +536,41 @@ def _outer_update_stale(
         rotate:  anchor' = O_r,  snapshot' = round r's endpoint
     """
     new_resid = state.residual
-    if handle is None:
-        # direct caller — no round body issued the collective early; start
-        # it here (identical numerics, no overlap to gain)
+    with jax.named_scope("line6"):
+        if handle is None:
+            # direct caller — no round body issued the collective early;
+            # start it here (identical numerics, no overlap to gain)
+            handle, new_resid = _stale_mean_start(cfg, state, backend)
         if cfg.compress_ratio is not None:
-            handle, new_resid = backend.worker_mean_sparse_start(
-                _stale_delta(state),
-                state.residual,
-                cfg.compress_ratio,
-                cfg.average_dtype,
-                mask=state.boundary_mask if cfg.masked_average else None,
+            # the in-flight value is the mean sparse DELTA against the
+            # anchor the snapshot's trajectory started from; rebuild the
+            # averaged endpoint at that same anchor (line 7 then subtracts
+            # it again)
+            x_tau = jax.tree.map(
+                lambda o, d: o + d,
+                state.stale_outer,
+                backend.worker_mean_done(handle),
             )
         else:
-            handle = backend.worker_mean_start(
-                state.boundary,
-                cfg.average_dtype,
-                mask=state.boundary_mask if cfg.masked_average else None,
-            )
-    if cfg.compress_ratio is not None:
-        # the in-flight value is the mean sparse DELTA against the anchor
-        # the snapshot's trajectory started from; rebuild the averaged
-        # endpoint at that same anchor (line 7 then subtracts it again)
-        x_tau = jax.tree.map(
-            lambda o, d: o + d,
-            state.stale_outer,
-            backend.worker_mean_done(handle),
-        )
-    else:
-        x_tau = backend.worker_mean_done(handle)
+            x_tau = backend.worker_mean_done(handle)
 
     # Line 7 anchored at the snapshot's start iterate.  The fused kernel
     # moves its x-input (the anchor) — that output is discarded (DCE'd);
     # only the momentum comes from it, line 8 moves the CURRENT iterate.
-    _, new_u = kops.slowmo_outer_update(
-        state.stale_outer,
-        x_tau,
-        state.slow_u,
-        gamma=lr,
-        alpha=cfg.alpha,
-        beta=cfg.beta,
-        use_pallas=cfg.use_pallas,
-    )
-    slow_step = cfg.alpha * lr
-    new_outer = jax.tree.map(
-        lambda o, u: o - slow_step * u, state.outer_params, new_u
-    )
+    with jax.named_scope("lines7_8"):
+        _, new_u = kops.slowmo_outer_update(
+            state.stale_outer,
+            x_tau,
+            state.slow_u,
+            gamma=lr,
+            alpha=cfg.alpha,
+            beta=cfg.beta,
+            use_pallas=cfg.use_pallas,
+        )
+        slow_step = cfg.alpha * lr
+        new_outer = jax.tree.map(
+            lambda o, u: o - slow_step * u, state.outer_params, new_u
+        )
 
     # rotate the double buffers: the next in-flight snapshot is this round's
     # (debiased) endpoint, anchored at the iterate its trajectory started
@@ -615,6 +608,24 @@ def _outer_update_stale(
         ),
         residual=new_resid,
     )
+
+
+def _stale_mean_start(cfg: SlowMoConfig, state: SlowMoState, backend):
+    """Issue the stale boundary's line-6 mean of the in-flight snapshot
+    (compressed: of its delta against its anchor), under the mask that rode
+    in with it.  Returns the pending handle and the residual after it."""
+    bmask = state.boundary_mask if cfg.masked_average else None
+    if cfg.compress_ratio is not None:
+        return backend.worker_mean_sparse_start(
+            _stale_delta(state),
+            state.residual,
+            cfg.compress_ratio,
+            cfg.average_dtype,
+            mask=bmask,
+        )
+    return backend.worker_mean_start(
+        state.boundary, cfg.average_dtype, mask=bmask
+    ), state.residual
 
 
 def _stale_delta(state: SlowMoState) -> PyTree:
@@ -728,19 +739,8 @@ def make_slowmo_round(
             # in-flight value is the mean sparse DELTA of the snapshot
             # against its anchor; the residual update is local and lands in
             # the mid-round state below.
-            bmask = state.boundary_mask if cfg.masked_average else None
-            if cfg.compress_ratio is not None:
-                pending, new_resid = backend.worker_mean_sparse_start(
-                    _stale_delta(state),
-                    state.residual,
-                    cfg.compress_ratio,
-                    cfg.average_dtype,
-                    mask=bmask,
-                )
-            else:
-                pending = backend.worker_mean_start(
-                    state.boundary, cfg.average_dtype, mask=bmask
-                )
+            with jax.named_scope("boundary"), jax.named_scope("line6"):
+                pending, new_resid = _stale_mean_start(cfg, state, backend)
 
         def body(k, acc):
             carry, loss_sum = acc

@@ -168,5 +168,6 @@ def flash_attention(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qp, kp, vp)
     return out[:, :, :Sq].transpose(0, 2, 1, 3)

@@ -56,4 +56,5 @@ def fused_nesterov_2d(
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_nesterov",
     )(lr2d, x, h, g)

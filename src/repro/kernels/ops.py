@@ -25,6 +25,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+@jax.named_scope("layout")
 def _to_2d(x: jax.Array, block_rows: int):
     """Flatten + zero-pad to (rows, LANES) with rows % block_rows == 0.
 
@@ -40,6 +41,7 @@ def _to_2d(x: jax.Array, block_rows: int):
     return flat.reshape(-1, LANES), n
 
 
+@jax.named_scope("layout")
 def _from_2d(y2d: jax.Array, n: int, shape) -> jax.Array:
     if y2d.size == n:
         return y2d.reshape(shape)

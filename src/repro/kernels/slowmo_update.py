@@ -67,4 +67,5 @@ def slowmo_update_2d(
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="slowmo_update",
     )(gamma2d, x0, x_tau, u)

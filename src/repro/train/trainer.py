@@ -13,8 +13,8 @@ rides ``SlowMoState`` through the same checkpoint pack/unpack path.
 from __future__ import annotations
 
 import dataclasses
-import time
-from typing import Any, Callable, Optional
+import functools
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,20 @@ class TrainConfig:
     ckpt_every: int = 0
     ckpt_path: str = ""
     grad_clip: float = 0.0  # global-norm clip, wired to InnerOptConfig.clip_norm
+
+
+class _Program(NamedTuple):
+    """What a round runs: the algorithm, the worker layout and the compiled
+    round built for them (the elastic loop rebuilds all three)."""
+
+    cfg: SlowMoConfig
+    layout: Any
+    round_fn: Callable
+
+
+def _span(phase: str):
+    """The host span of one phase of a round, nested in its ``train_round``."""
+    return jax.profiler.TraceAnnotation(f"train:{phase}")
 
 
 def make_lr_fn(tc: TrainConfig, tau: int = 1):
@@ -196,7 +210,6 @@ class Trainer:
         if self.elastic is not None:
             return self._run_elastic(state, rounds)
         start = int(jax.device_get(state.outer_step))
-        t0 = time.perf_counter()
         # a masked round (cfg.masked_average without the elastic loop) takes
         # the all-ones participation vector — bit-identical to unmasked
         full_mask = (
@@ -204,34 +217,111 @@ class Trainer:
             if self.smcfg.masked_average
             else ()
         )
+
+        def dispatch(round_fn, state, batches, lr):
+            return round_fn(state, batches, lr, *full_mask)
+
+        prog = _Program(self.smcfg, self.layout, self.round_fn)
         for r in range(start, start + rounds):
-            lr = self.lr_fn(r * self.smcfg.tau)
-            batches = self._batches(r)
-            state, metrics = self.round_fn(state, batches, lr, *full_mask)
-            rec = {
-                "round": r,
-                "inner_steps": (r + 1) * self.smcfg.tau,
-                "loss": float(metrics["loss"]),
-                "lr": float(lr),
-                "wall_s": time.perf_counter() - t0,
-            }
-            if "drift" in metrics:
-                rec["drift"] = float(metrics["drift"])
-            if self.eval_fn and (r % max(self.tc.log_every, 1) == 0 or r == start + rounds - 1):
-                rec["eval"] = float(
-                    self.eval_fn(_eval_params(self.smcfg, state, self.pack))
-                )
+            state, prog = self._round(
+                r, state, prog, dispatch, last=r == start + rounds - 1
+            )
+        return state
+
+    def _round(self, r, state, prog, dispatch, *, last, reconfigure=None,
+               columns=None, **fields):
+        """Round ``r`` of either loop, under its profiler spans: the step
+        span ``train_round`` (``step_num=r``) over ``train:reconfigure``
+        (elastic only: the state resized and the round rebuilt, so a
+        recompile shows here), ``train:sample`` (the batches; ``columns``
+        keeps those workers' columns), ``train:dispatch`` (the compiled
+        round's call, ``dispatch(round_fn, state, batches, lr)``),
+        ``train:sync`` (the metric reads that wait for the device),
+        ``train:eval`` and ``train:ckpt``.  ``reconfigure(state, prog)``
+        returns the resized state and rebuilt program; ``fields`` join the
+        round's history record.  Returns the new state and program."""
+        with jax.profiler.StepTraceAnnotation("train_round", step_num=r):
+            if reconfigure is not None:
+                with _span("reconfigure"):
+                    state, prog = reconfigure(state, prog)
+            cfg = prog.cfg
+            lr = self.lr_fn(r * cfg.tau)
+            with _span("sample"):
+                batches = self._batches(r)
+                if columns is not None:
+                    batches = jax.tree.map(
+                        lambda x: jnp.take(x, columns, axis=1)
+                        if getattr(x, "ndim", 0) >= 2
+                        else x,
+                        batches,
+                    )
+            with _span("dispatch"):
+                state, metrics = dispatch(prog.round_fn, state, batches, lr)
+            with _span("sync"):
+                rec = {
+                    "round": r,
+                    "inner_steps": (r + 1) * cfg.tau,
+                    "loss": float(metrics["loss"]),
+                    "lr": float(lr),
+                    **fields,
+                }
+                if "drift" in metrics:
+                    rec["drift"] = float(metrics["drift"])
+            if self.eval_fn and (r % max(self.tc.log_every, 1) == 0 or last):
+                with _span("eval"):
+                    rec["eval"] = float(
+                        self.eval_fn(_eval_params(cfg, state, self.pack))
+                    )
             self.history.append(rec)
             if self.tc.log_every and r % self.tc.log_every == 0:
-                drift = f" drift={rec.get('drift', float('nan')):.3e}" if "drift" in rec else ""
+                extra = "".join(f" {k}={v}" for k, v in fields.items())
+                drift = f" drift={rec['drift']:.3e}" if "drift" in rec else ""
                 ev = f" eval={rec['eval']:.4f}" if "eval" in rec else ""
                 print(
                     f"round {r:4d} step {rec['inner_steps']:6d} "
-                    f"loss {rec['loss']:.4f} lr {rec['lr']:.2e}{drift}{ev}"
+                    f"loss {rec['loss']:.4f} lr {rec['lr']:.2e}{extra}{drift}{ev}"
                 )
-            if self.tc.ckpt_every and self.tc.ckpt_path and (r + 1) % self.tc.ckpt_every == 0:
-                ckpt_lib.save_state(self.tc.ckpt_path, state, step=r + 1, pack=self.pack)
-        return state
+            if (
+                self.tc.ckpt_every
+                and self.tc.ckpt_path
+                and (r + 1) % self.tc.ckpt_every == 0
+            ):
+                with _span("ckpt"):
+                    ckpt_lib.save_state(
+                        self.tc.ckpt_path, state, step=r + 1, pack=self.pack
+                    )
+        return state, prog
+
+    def _reconfigure(self, state, prog, *, prev, members):
+        """The state and program for a new ordered member set: the state
+        sliced to the survivors (evict) or grown from the rebroadcast outer
+        state (rejoin), the layout and the round rebuilt for it."""
+        from ..elastic import reconfigure
+
+        cfg = dataclasses.replace(prog.cfg, num_workers=len(members))
+        if any(w not in prev for w in members):
+            # rejoin: survivors keep their slots, new slots fill from the
+            # rebroadcast outer state
+            state = reconfigure.admit_state(
+                cfg, state, prev, members, pack=self.pack
+            )
+        else:
+            # evict: slice the survivor POSITIONS within the previous
+            # ordered member list
+            keep = [prev.index(w) for w in members]
+            state = reconfigure.survivor_state(prog.cfg, state, keep)
+        layout = prog.layout
+        if layout is not None:
+            from ..distributed import spmd as spmd_lib
+            from ..launch import mesh as mesh_lib
+
+            layout = mesh_lib.make_survivor_layout(self.layout, members)
+            # the reconfigured state still lives on the OLD mesh's devices;
+            # commit it to the survivor mesh explicitly
+            state = jax.device_put(
+                state, spmd_lib.state_shardings(cfg, layout, state)
+            )
+        return state, _Program(cfg, layout, self._build_round(cfg, layout))
 
     def _run_elastic(self, state: SlowMoState, rounds: int):
         """The elastic round loop: heartbeats -> evict/rejoin at the
@@ -244,15 +334,14 @@ class Trainer:
         so a run that loses worker w reproduces, round for round, a fresh
         survivor-only run seeded from the boundary state (the kill-a-worker
         oracle in tests/test_elastic.py)."""
-        from ..elastic import ElasticCoordinator, reconfigure
+        from ..elastic import ElasticCoordinator
         from ..elastic.faults import FaultPlan, TransientWorkerError
 
         plan = self.faults or FaultPlan()
         W0 = self.smcfg.num_workers
         coord = ElasticCoordinator(range(W0), self.elastic)
-        cur_cfg, cur_layout, cur_round = self.smcfg, self.layout, self.round_fn
+        prog = _Program(self.smcfg, self.layout, self.round_fn)
         start = int(jax.device_get(state.outer_step))
-        t0 = time.perf_counter()
         for r in range(start, start + rounds):
             # 1. heartbeats, replayed from the fault plan: every member the
             # plan has not killed reports in for round r
@@ -266,110 +355,46 @@ class Trainer:
             for w in plan.rejoins(r):
                 coord.rejoin(w, r)
             members = coord.members
+            resize = None
             if members != prev:
-                grown = [w for w in members if w not in prev]
-                if grown:
-                    # rejoin: survivors keep their slots, new slots fill
-                    # from the rebroadcast outer state
-                    state = reconfigure.admit_state(
-                        dataclasses.replace(cur_cfg, num_workers=len(members)),
-                        state,
-                        prev,
-                        members,
-                        pack=self.pack,
-                    )
-                else:
-                    # evict: slice the survivor POSITIONS within the
-                    # previous ordered member list
-                    keep = [prev.index(w) for w in members]
-                    state = reconfigure.survivor_state(cur_cfg, state, keep)
-                cur_cfg = dataclasses.replace(cur_cfg, num_workers=len(members))
-                if cur_layout is not None:
-                    from ..distributed import spmd as spmd_lib
-                    from ..launch import mesh as mesh_lib
-
-                    cur_layout = mesh_lib.make_survivor_layout(
-                        self.layout, members
-                    )
-                    # the reconfigured state still lives on the OLD mesh's
-                    # devices; commit it to the survivor mesh explicitly
-                    state = jax.device_put(
-                        state,
-                        spmd_lib.state_shardings(cur_cfg, cur_layout, state),
-                    )
-                cur_round = self._build_round(cur_cfg, cur_layout)
+                resize = functools.partial(
+                    self._reconfigure, prev=prev, members=members
+                )
             # 3. this round's participation mask: plan-delayed stragglers
             # plus silent-but-not-yet-evicted workers (detection window)
             extra = ()
-            if cur_cfg.masked_average:
-                out = plan.delayed(r, cur_cfg.tau) | set(coord.silent(r))
+            if self.smcfg.masked_average:
+                out = plan.delayed(r, self.smcfg.tau) | set(coord.silent(r))
                 mvec = np.asarray(
                     [0.0 if w in out else 1.0 for w in members], np.float32
                 )
                 if not mvec.any():  # never mask every worker out of line 6
                     mvec[:] = 1.0
                 extra = (jnp.asarray(mvec),)
-            # 4. batches: survivor columns of the full-W sample, so every
-            # surviving worker consumes exactly its uninterrupted data stream
-            lr = self.lr_fn(r * cur_cfg.tau)
-            full = self._batches(r)
-            if members == tuple(range(W0)):
-                batches = full
-            else:
-                idx = np.asarray(members)
-                batches = jax.tree.map(
-                    lambda x: jnp.take(x, idx, axis=1)
-                    if getattr(x, "ndim", 0) >= 2
-                    else x,
-                    full,
-                )
 
-            # 5. the boundary step, retried with backoff; injected flaky
+            # 4. the boundary step, retried with backoff; injected flaky
             # failures raise BEFORE the donated call, so state is intact
-            fail_n = plan.flaky_attempts(r)
+            def dispatch(round_fn, state, batches, lr, extra=extra,
+                         fail_n=plan.flaky_attempts(r), r=r):
+                def attempt(k):
+                    if k < fail_n:
+                        raise TransientWorkerError(
+                            f"injected boundary failure {k + 1}/{fail_n} at round {r}"
+                        )
+                    return round_fn(state, batches, lr, *extra)
 
-            def attempt(k, state=state, batches=batches, lr=lr, extra=extra,
-                        fail_n=fail_n, r=r, cur_round=cur_round):
-                if k < fail_n:
-                    raise TransientWorkerError(
-                        f"injected boundary failure {k + 1}/{fail_n} at round {r}"
-                    )
-                return cur_round(state, batches, lr, *extra)
+                return coord.run_boundary(attempt)
 
-            state, metrics = coord.run_boundary(attempt)
-            rec = {
-                "round": r,
-                "inner_steps": (r + 1) * cur_cfg.tau,
-                "loss": float(metrics["loss"]),
-                "lr": float(lr),
-                "workers": len(members),
-                "masked_out": int(len(members) - int(extra[0].sum()))
-                if extra
-                else 0,
-                "wall_s": time.perf_counter() - t0,
-            }
-            if "drift" in metrics:
-                rec["drift"] = float(metrics["drift"])
-            if self.eval_fn and (
-                r % max(self.tc.log_every, 1) == 0 or r == start + rounds - 1
-            ):
-                rec["eval"] = float(
-                    self.eval_fn(_eval_params(cur_cfg, state, self.pack))
-                )
-            self.history.append(rec)
-            if self.tc.log_every and r % self.tc.log_every == 0:
-                print(
-                    f"round {r:4d} W={rec['workers']} loss {rec['loss']:.4f} "
-                    f"lr {rec['lr']:.2e} masked={rec['masked_out']}"
-                )
-            if (
-                self.tc.ckpt_every
-                and self.tc.ckpt_path
-                and (r + 1) % self.tc.ckpt_every == 0
-            ):
-                ckpt_lib.save_state(
-                    self.tc.ckpt_path, state, step=r + 1, pack=self.pack
-                )
+            # 5. batches: survivor columns of the full-W sample, so every
+            # surviving worker consumes exactly its uninterrupted data stream
+            state, prog = self._round(
+                r, state, prog, dispatch,
+                last=r == start + rounds - 1,
+                reconfigure=resize,
+                columns=None if members == tuple(range(W0)) else np.asarray(members),
+                workers=len(members),
+                masked_out=int(len(members) - int(extra[0].sum())) if extra else 0,
+            )
         return state
 
 

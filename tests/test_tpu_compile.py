@@ -8,6 +8,7 @@ process at a time may load the TPU library, so every test of this kind
 lives in this one file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +57,16 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _kernel_names(compiled) -> set:
+    """The instruction names of the kernel calls, numbering dropped: a
+    device trace names each kernel event by its instruction."""
+    return {
+        re.match(r"\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)* = ", line).group(1)
+        for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    }
+
+
 def test_slowmo_update_compiles(one_chip, no_persistent_cache):
     s = jax.ShapeDtypeStruct(ROWS, jnp.float32, sharding=one_chip)
     g = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
@@ -78,6 +89,27 @@ def test_fused_nesterov_compiles(one_chip, no_persistent_cache, dtype):
         x, s, s, lr,
     )
     assert _kernel_calls(c) == 1
+
+
+def test_kernel_calls_carry_their_names(one_chip, no_persistent_cache):
+    """Each kernel's ``name=`` names its custom call and ends its op path."""
+    s = jax.ShapeDtypeStruct(ROWS, jnp.float32, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    kernels = {
+        "fused_nesterov": lambda a, h, gr, r: fused_nesterov.fused_nesterov_2d(
+            a, h, gr, r, momentum=0.9
+        ),
+        "slowmo_update": lambda a, b, u, gamma: slowmo_update.slowmo_update_2d(
+            a, b, u, gamma, alpha=1.0, beta=0.7
+        ),
+    }
+    for name, fn in kernels.items():
+        c = _compile(fn, s, s, s, g)
+        assert _kernel_names(c) == {name}
+        calls = [
+            line for line in c.as_text().splitlines() if "tpu_custom_call" in line
+        ]
+        assert all(f'/{name}/pallas_call"' in line for line in calls), calls
 
 
 @pytest.mark.parametrize(
@@ -121,3 +153,4 @@ def test_training_round_compiles_with_kernels(topo, no_persistent_cache, monkeyp
     fn = spmd.build_spmd_round(cfg, model.loss_fn, layout, state, batches, pack)
     c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
     assert _kernel_calls(c) >= 2
+    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update"}
