@@ -230,9 +230,10 @@ def apply_step(
     Nesterov SGD evaluated at ``params`` itself, ``use_pallas`` routes the
     momentum + look-ahead + parameter step through the fused kernel — one HBM
     pass and (on packed state) a single launch — instead of separate
-    h-update / d / axpy passes.  Gradient clipping composes: it is applied to
-    ``grads`` before the kernel, with ``sq_fn`` (``make_grad_sq_fn``)
-    supplying the TP-aware global norm on tensor-parallel backends.
+    h-update / d / axpy passes; unclipped gradients reach it in their own
+    dtype.  Gradient clipping composes: it is applied to fp32 ``grads``
+    before the kernel, with ``sq_fn`` (``make_grad_sq_fn``) supplying the
+    TP-aware global norm on tensor-parallel backends.
     """
     fused = use_pallas and z is None and cfg.kind == "sgd" and cfg.nesterov
     if not fused:
@@ -248,7 +249,8 @@ def apply_step(
 
     from ..kernels import ops as kops  # local import: kernels are optional
 
-    grads = _clip(cfg, jax.tree.map(lambda g: g.astype(jnp.float32), grads), sq_fn)
+    if cfg.clip_norm:  # the clip scales in fp32; unclipped, the kernel casts
+        grads = _clip(cfg, jax.tree.map(lambda g: g.astype(jnp.float32), grads), sq_fn)
     x_new, h_new = kops.fused_nesterov_update(
         params,
         state.h,

@@ -1,13 +1,12 @@
 """Flat-buffer packing: one contiguous (rows, 1024) buffer per dtype group.
 
 SlowMo's boundary cost is per-*leaf* everywhere the state is a pytree: one
-``pallas_call`` (plus a flatten/pad copy) per parameter leaf in
-``kernels/ops.py`` and one all-reduce / collective-permute per leaf on the
-mesh backend.  Packing the state once at init into a few dtype-homogeneous
-``(rows, LANES)`` buffers with a *static* leaf-offset index turns the outer
-boundary into ONE kernel launch and ONE collective, and the tree layout is
-recovered only where it is semantically needed (the ``loss_fn`` boundary and
-checkpoints).
+``pallas_call`` per parameter leaf in ``kernels/ops.py`` and one
+all-reduce / collective-permute per leaf on the mesh backend.  Packing the
+state once at init into a few dtype-homogeneous ``(rows, LANES)`` buffers
+with a *static* leaf-offset index turns the outer boundary into ONE kernel
+launch and ONE collective, and the tree layout is recovered only where it
+is semantically needed (the ``loss_fn`` boundary and checkpoints).
 
 Design:
 
@@ -41,8 +40,8 @@ PyTree = Any
 
 LANES = 1024  # matches kernels/ops.py tiling
 # Rows per buffer are padded to this multiple so the kernel dispatcher
-# (kernels/ops.py::_pick_block_rows) always finds an exactly-dividing block
-# size >= 64 and takes the copy-free reshape path; the cost is < 64*LANES
+# (kernels/ops.py::_tiling) always finds an exactly-dividing block size
+# >= 64 and takes the copy-free path; the cost is < 64*LANES
 # elements of tail padding per buffer (256 KiB fp32) — noise for real models.
 ROW_ALIGN = 64
 

@@ -7,10 +7,11 @@ exactly once and writes each output once — the op is memory-bound, so this
 halves HBM traffic for the outer boundary (which for large N dominates the
 SlowMo overhead on-chip).
 
-Layout: the wrapper flattens/pads each leaf to (rows, 1024) so blocks are
-(block_rows, 1024) fp32 tiles in VMEM — lane-dim 1024 = 8*128 keeps the VPU
-fully utilised; 1024*4B rows fit comfortably in VMEM at block_rows<=512
-(3 inputs + 2 outputs = 5 * 512 * 1024 * 4B = 10 MiB < 16 MiB VMEM).
+Layout: the wrapper (``kernels/ops.py``) views each leaf as (rows, cols)
+and picks (block_rows, block_cols) fp32 blocks of about 256K elements from
+its shape — packed buffers are (rows, 1024) in (256 or 64, 1024) blocks;
+3 inputs + 2 outputs = 5 * 256K * 4B = 5 MiB, 10 MiB double-buffered, under
+the 16 MiB of VMEM.
 gamma (the fast LR, traced) is staged through SMEM as a (1,1) scalar.
 """
 from __future__ import annotations
@@ -44,14 +45,18 @@ def slowmo_update_2d(
     alpha: float,
     beta: float,
     block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_cols: int | None = None,
     interpret: bool = False,
 ):
-    """Fused update on (rows, LANES) fp32 arrays. Returns (x_new, u_new)."""
-    rows, lanes = x0.shape
-    assert lanes == LANES and rows % block_rows == 0, (x0.shape, block_rows)
+    """Fused update on (rows, cols) fp32 arrays in (block_rows, block_cols)
+    blocks (``block_cols`` None: the whole width). Returns (x_new, u_new)."""
+    rows, cols = x0.shape
+    block_cols = block_cols or cols
+    assert rows % block_rows == 0 and cols % block_cols == 0, (
+        x0.shape, block_rows, block_cols)
     gamma2d = jnp.asarray(gamma, jnp.float32).reshape(1, 1)
-    grid = (rows // block_rows,)
-    blk = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
+    grid = (rows // block_rows, cols // block_cols)
+    blk = pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_kernel, alpha=alpha, beta=beta),
         grid=grid,
@@ -63,8 +68,8 @@ def slowmo_update_2d(
         ],
         out_specs=[blk, blk],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((rows, cols), jnp.float32),
+            jax.ShapeDtypeStruct((rows, cols), jnp.float32),
         ],
         interpret=interpret,
         name="slowmo_update",

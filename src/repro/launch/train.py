@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from ..configs import ARCH_IDS, get_config
 from ..core import slowmo
 from ..data import MarkovLMConfig, make_audio_sampler, make_markov_sampler
+from ..kernels import ops as kernel_ops
 from ..models import build_model, param_count
 from ..train import TrainConfig, Trainer
 from ..train import checkpoint as ckpt_lib
@@ -248,7 +249,12 @@ def main(argv=None):
             return
         state = jax.tree.map(jnp.asarray, state)
     rounds = args.rounds if state is None else args.rounds - int(state.outer_step)
-    trainer.run(state=state, rounds=rounds)
+    # the tally is taken while the round traces; it is printed when the run
+    # returns, as splitting the run would restart the elastic loop's state
+    with kernel_ops.tally() as tally:
+        trainer.run(state=state, rounds=rounds)
+    if trainer.smcfg.use_pallas:
+        print(f"fused kernels, as traced: {tally.summary()}")
 
 
 if __name__ == "__main__":

@@ -1,6 +1,8 @@
 """Per-kernel allclose tests: Pallas (interpret=True) vs the pure-jnp oracle,
 swept over shapes and dtypes, plus hypothesis property tests on the math."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,6 +85,46 @@ class TestFusedNesterovKernel:
             np.asarray(xk, np.float32), np.asarray(xr, np.float32), rtol=2e-2 if dtype == jnp.bfloat16 else 1e-6, atol=1e-5
         )
         np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize(
+        "shape,copy_free",
+        [
+            ((1, 2, 256, 512), True),  # olmo-like (W, L, d, d_ff) leaves
+            ((1, 2, 512, 256), True),
+            ((1, 1000, 256), True),  # one full-height block
+            ((4, 512, 256), True),  # two blocks
+            ((3, 100), False),  # odd width: flattened and padded
+            ((7,), False),
+        ],
+    )
+    @pytest.mark.parametrize("g_dtype", [jnp.bfloat16, jnp.float32], ids=["g_bf16", "g_f32"])
+    @pytest.mark.parametrize("wd", [0.0, 1e-4])
+    def test_tree_wrapper_is_bitwise_ref(self, shape, copy_free, g_dtype, wd):
+        """Each leaf in its own layout and dtype: the kernel's results equal
+        the oracle's bit for bit, and the tally says which path it took."""
+        x = rnd(0, shape, jnp.bfloat16)
+        h = rnd(1, shape)
+        g = rnd(2, shape, g_dtype)
+        with ops.tally() as tally:
+            xk, hk = ops.fused_nesterov_update(
+                {"w": x}, {"w": h}, {"w": g}, lr=0.1, momentum=0.9,
+                weight_decay=wd, use_pallas=True,
+            )
+        # the oracle compiled, as the kernel's body is: op by op, eagerly,
+        # each product is rounded on its own, which a fusion need not do
+        oracle = jax.jit(functools.partial(
+            ref.fused_nesterov_ref, momentum=0.9, weight_decay=wd))
+        xr, hr = oracle(x, h, g, lr=jnp.float32(0.1))
+        assert xk["w"].dtype == x.dtype and hk["w"].dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(xk["w"]).view(np.uint16), np.asarray(xr).view(np.uint16)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(hk["w"]).view(np.uint32), np.asarray(hr).view(np.uint32)
+        )
+        nbytes = x.size * (2 + 4 + g.dtype.itemsize + 2 + 4)
+        taken, other = ("copy_free", "padded") if copy_free else ("padded", "copy_free")
+        assert tally.counts == {"fused_nesterov": {taken: [1, nbytes], other: [0, 0]}}
 
 
 class TestFlashAttentionKernel:

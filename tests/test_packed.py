@@ -289,40 +289,40 @@ class TestBlockRowPadding:
         27 rows of pad instead of 219."""
         x = jnp.zeros((300_000,))
         raw_rows = -(-x.size // ops.LANES)  # 293
-        br = ops._pick_block_rows(x)
-        x2d, n = ops._to_2d(x, br)
-        assert n == x.size
-        assert x2d.shape[0] % br == 0
+        t = ops._tiling(x.shape)
+        x2d = ops._to_2d(x, t)
+        assert not t.copy_free and t.block_rows == 64
+        assert x2d.shape[0] % t.block_rows == 0
         assert x2d.shape[0] - raw_rows <= max(7, raw_rows // 8)  # was 219 rows
 
     def test_large_leaves_keep_large_blocks(self):
         """Near-tile-aligned big leaves must not degrade to 8-row blocks:
         the relative-waste rule keeps 256-row tiles when the pad is <1%."""
-        x = jnp.zeros((25144 * ops.LANES,))  # rows divisible by 8, not 64
-        assert ops._pick_block_rows(x) == 256
+        assert ops._tiling((25144 * ops.LANES,)).block_rows == 256  # rows % 64 != 0
         # and packed buffers (64-row aligned) always divide exactly
-        assert ops._pick_block_rows(jnp.zeros((64, ops.LANES))) == 64
-        assert ops._pick_block_rows(jnp.zeros((512, ops.LANES))) == 256
+        assert ops._tiling((64, ops.LANES)).block_rows == 64
+        assert ops._tiling((512, ops.LANES)).block_rows == 256
+        assert ops._tiling((3, 192, ops.LANES)).block_rows == 64
 
     @pytest.mark.parametrize("size", [3, 1024, 5000, 8 * 1024, 293 * 1024, 2**18])
     def test_pick_divides_padded_rows(self, size):
         x = jnp.zeros((size,))
-        br = ops._pick_block_rows(x)
-        x2d, n = ops._to_2d(x, br)
-        assert x2d.shape == ((x2d.size // ops.LANES), ops.LANES)
-        assert x2d.shape[0] % br == 0 and n == size
+        t = ops._tiling(x.shape)
+        x2d = ops._to_2d(x, t)
+        assert x2d.shape == ((x2d.size // ops.LANES), ops.LANES) == (t.rows, t.cols)
+        assert x2d.shape[0] % t.block_rows == 0
+        assert ops._from_2d(x2d, t, x.shape).shape == x.shape
 
     def test_aligned_buffer_is_not_copied(self):
         """Packed buffers ((rows, LANES), rows % block == 0) take the reshape
         fast path — the returned 2D view has exactly the input's elements."""
         x = jnp.arange(8 * ops.LANES, dtype=jnp.float32).reshape(8, ops.LANES)
-        br = ops._pick_block_rows(x)
-        x2d, n = ops._to_2d(x, br)
-        assert x2d.shape == (8, ops.LANES) and n == x.size
+        t = ops._tiling(x.shape)
+        assert t.copy_free and ops._to_2d(x, t).shape == (8, ops.LANES)
         # and a worker-stacked packed buffer flattens without padding
         xw = jnp.stack([x, x])
-        x2d, n = ops._to_2d(xw, ops._pick_block_rows(xw))
-        assert x2d.shape == (16, ops.LANES) and n == xw.size
+        t = ops._tiling(xw.shape)
+        assert t.copy_free and ops._to_2d(xw, t).shape == (16, ops.LANES)
 
 
 def dummy_model():

@@ -64,8 +64,9 @@ def test_round_scopes(slowmo_round_ops):
     paths = [p for _, p in slowmo_round_ops]
     for token in ("fwd_bwd", "inner_opt", "layout", "boundary", "line6", "lines7_8"):
         assert any(_has(p, token) for p in paths), token
-    # the tile conversions of the kernel step sit inside the inner optimizer
-    assert any(_has(p, "inner_opt") and _has(p, "layout") for p in paths)
+    # the kernel step takes each leaf as it is: no tile conversion, so no
+    # layout op inside the inner optimizer
+    assert not any(_has(p, "inner_opt") and _has(p, "layout") for p in paths)
 
 
 @pytest.mark.parametrize(

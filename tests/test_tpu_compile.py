@@ -67,6 +67,103 @@ def _kernel_names(compiled) -> set:
     }
 
 
+def _entry(hlo: str) -> dict:
+    """The entry computation's instructions: name -> (opcode, shape with
+    layout, operand names)."""
+    body = hlo.split("\nENTRY ", 1)[1].split("\n}", 1)[0]
+    out = {}
+    for line in body.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.-]+) = ", line)
+        rest = line[m.end():]
+        depth = 0
+        for i, ch in enumerate(rest):  # a tuple shape holds spaces
+            depth += (ch == "(") - (ch == ")")
+            if ch == " " and depth == 0:
+                break
+        shape, rest = rest[:i], rest[i + 1:]
+        opcode, args = rest.split("(", 1)
+        depth, end = 1, 0
+        while depth:
+            depth += (args[end] == "(") - (args[end] == ")")
+            end += 1
+        out[m.group(1)] = (opcode, shape, re.findall(r"%([\w.-]+)", args[:end]))
+    return out
+
+
+def _moves_nothing(entry: dict, name: str) -> bool:
+    """A bitcast, a tuple element, or a copy or prefetch (``copy-start``/
+    ``copy-done``) that keeps its operand's shape and layout; the memory
+    space (``S(n)``) is not layout."""
+    opcode, shape, operands = entry[name]
+    if opcode in ("bitcast", "get-tuple-element", "copy-done"):
+        return True  # a copy-done finishes the copy-start checked below
+    def layout(s):
+        return re.sub(r"S\(\d+\)", "", s)
+    source = layout(entry[operands[0]][1]) if operands else None
+    if opcode == "copy":
+        return source == layout(shape)
+    return opcode == "copy-start" and layout(shape).startswith(f"({source}, ")
+
+
+def _sources(entry: dict, name: str) -> set:
+    """Where ``name``'s value comes from, through instructions that move
+    nothing."""
+    if not _moves_nothing(entry, name):
+        return {f"{entry[name][0]} %{name}"}
+    return set().union(*(_sources(entry, o) for o in entry[name][2]))
+
+
+def _sinks(entry: dict, name: str) -> set:
+    """Where ``name``'s value goes, through instructions that move nothing."""
+    out = set()
+    for user, (opcode, _, operands) in entry.items():
+        if name in operands:
+            out |= _sinks(entry, user) if _moves_nothing(entry, user) else {
+                f"{opcode} %{user}"}
+    return out
+
+
+def test_fused_nesterov_takes_leaves_as_they_are(one_chip, no_persistent_cache, monkeypatch):
+    """The tree-layout inner step on olmo-1b's leaves (width 2048, depth 2,
+    one worker): every operand and result of every kernel call is the
+    leaf's own buffer, in its own layout and dtype — no reshape, transpose,
+    convert, layout-changing copy or fusion feeds or leaves the kernel."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import build_model
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build_model(get_config("olmo-1b", reduced=False).replace(n_layers=2))
+    pshape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def leaves(dtype):
+        return jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct((1,) + p.shape, dtype, sharding=one_chip),
+            pshape,
+        )
+
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    with ops.tally() as tally:
+        c = _compile(
+            lambda x, h, g, r: ops.fused_nesterov_update(
+                x, h, g, lr=r, momentum=0.9, use_pallas=True
+            ),
+            leaves(jnp.bfloat16), leaves(jnp.float32), leaves(jnp.bfloat16), lr,
+        )
+    n = len(jax.tree.leaves(pshape))
+    assert tally.summary().startswith(f"fused_nesterov: {n} of {n} leaves copy-free (100.0%")
+    entry = _entry(c.as_text())
+    calls = [
+        k for k, v in entry.items()
+        if v[0] == "custom-call" and k.startswith("fused_nesterov")
+    ]
+    assert len(calls) == n
+    for call in calls:
+        feeds = set().union(*(_sources(entry, o) for o in entry[call][2]))
+        assert {f.split()[0] for f in feeds} == {"parameter"}, (call, feeds)
+        assert {f.split()[0] for f in _sinks(entry, call)} == {"tuple"}, call
+
+
 def test_slowmo_update_compiles(one_chip, no_persistent_cache):
     s = jax.ShapeDtypeStruct(ROWS, jnp.float32, sharding=one_chip)
     g = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
