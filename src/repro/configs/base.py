@@ -14,6 +14,18 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling``)."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # 'dense' | 'moe' | 'xlstm' | 'rglru'
@@ -44,10 +56,18 @@ class ModelConfig:
     moe_d_ff: int = 0
     first_k_dense: int = 0  # leading dense layers before MoE starts
     dense_d_ff: int = 0  # d_ff of those leading dense layers
-    capacity_factor: float = 1.25
-    moe_group_size: int = 2048
-    moe_dispatch: str = "onehot_ec"  # "onehot_ec" (GShard baseline) | "compact" (§Perf)
+    experts_held: int = 0  # experts 0 .. H-1 this layer computes; 0 = all
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum 1
+    moe_aux: str = "switch"  # 'switch': E sum f_e p_e, top-1, in the loss;
+    # 'seq': DeepSeek-V2's per-sequence balance loss, in the gradient only
     aux_loss_coef: float = 0.01
+    # multi-head latent attention (DeepSeek-V2); the kind follows from
+    # kv_lora_rank > 0.  No q compression: q comes from one projection.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Optional["Yarn"] = None  # YaRN scaling of the rope frequencies
     # xLSTM
     slstm_every: int = 0  # every k-th block is sLSTM (0 => all mLSTM)
     chunk_size: int = 256
@@ -65,6 +85,10 @@ class ModelConfig:
     unroll_layers: bool = False  # unroll scan-over-layers (dry-run cost analysis)
     attn_chunk: int = 1024  # kv-chunk for the chunked (online-softmax) impl
     source: str = ""  # citation
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def resolved_head_dim(self) -> int:
@@ -109,6 +133,7 @@ ARCH_IDS = [
     "olmo-1b",
     "chameleon-34b",
     "qwen3-4b",
+    "deepseek-v2-lite",
 ]
 
 

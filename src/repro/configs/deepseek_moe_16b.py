@@ -17,5 +17,4 @@ REDUCED = CONFIG.replace(
     n_layers=2, d_model=256, n_heads=4, n_kv_heads=4,
     moe_d_ff=128, d_ff=128, dense_d_ff=512, n_experts=4, top_k=2,
     n_shared_experts=1, vocab_size=512, dtype=jnp.float32, remat=False,
-    moe_group_size=64,
 )

@@ -164,17 +164,9 @@ class PackSpec:
     def unpack(self, packed: Packed, dtype=None) -> PyTree:
         """Recover the pytree; leaves keep the buffer's storage dtype unless
         ``dtype`` is given.  Slices + reshapes only — no arithmetic."""
-        some = next(iter(packed.buffers.values()))
-        lead = tuple(some.shape[:-2])
-        flats = {
-            g: packed[g].reshape(lead + (-1,)) for g, _ in self.group_rows
-        }
         leaves = []
         for slot in self.slots:
-            flat = flats[slot.group]
-            leaf = jax.lax.slice_in_dim(
-                flat, slot.offset, slot.offset + slot.size, axis=len(lead)
-            ).reshape(lead + slot.shape)
+            leaf = _slot_view(packed[slot.group], slot)
             leaves.append(leaf.astype(dtype) if dtype is not None else leaf)
         return jax.tree.unflatten(self.treedef, leaves)
 
@@ -184,12 +176,7 @@ class PackSpec:
         if len(matches) != 1:
             raise KeyError(f"{key!r} matches {len(matches)} leaves")
         slot = matches[0]
-        buf = packed[slot.group]
-        lead = tuple(buf.shape[:-2])
-        flat = buf.reshape(lead + (-1,))
-        return jax.lax.slice_in_dim(
-            flat, slot.offset, slot.offset + slot.size, axis=len(lead)
-        ).reshape(lead + slot.shape)
+        return _slot_view(packed[slot.group], slot)
 
     def zeros(self, lead: tuple[int, ...] = (), dtype=None) -> Packed:
         """Packed zeros with the same layout (momentum-buffer init)."""
@@ -204,6 +191,21 @@ class PackSpec:
         """Per-group scalar zeros: the zero-cost placeholder layout (SGD's
         unused second-moment slot, gossip's unused stale messages)."""
         return Packed({g: jnp.zeros((), dtype) for g, _ in self.group_rows})
+
+
+def _slot_view(buf: jax.Array, slot: LeafSlot) -> jax.Array:
+    """The leaf ``slot`` out of a ``lead + (rows, LANES)`` buffer: the rows
+    it spans, flattened, then its elements.  Flattening only those rows keeps
+    any relayout the flattening needs to the leaf's own size (a whole
+    buffer's would hold a second copy of it)."""
+    lead = tuple(buf.shape[:-2])
+    r0 = slot.offset // LANES
+    r1 = -(-(slot.offset + slot.size) // LANES)
+    rows = jax.lax.slice_in_dim(buf, r0, r1, axis=len(lead)).reshape(lead + (-1,))
+    start = slot.offset - r0 * LANES
+    return jax.lax.slice_in_dim(rows, start, start + slot.size, axis=len(lead)).reshape(
+        lead + slot.shape
+    )
 
 
 def make_pack_spec(tree: PyTree) -> PackSpec:

@@ -334,7 +334,7 @@ def make_inner_step(
     """
     backend = backend or comm.AxisBackend(cfg.num_workers)
     loss_fn = comm.bind_loss(loss_fn, backend)
-    vgrad = jax.vmap(jax.value_and_grad(loss_fn))
+    vgrad = jax.vmap(jax.value_and_grad(_with_stats(loss_fn), has_aux=True))
     gcfg = cfg.gossip_config
 
     def step_fn(carry, batch, lr):
@@ -346,7 +346,7 @@ def make_inner_step(
             z = params
         z_tree = pack.unpack(z) if pack is not None else z
         with jax.named_scope("fwd_bwd"):
-            losses, grads = vgrad(z_tree, batch)
+            (losses, stats), grads = vgrad(z_tree, batch)
         if pack is not None:
             grads = pack.pack(grads, dtype=jnp.float32)
         with jax.named_scope("grad_sync"):
@@ -386,9 +386,21 @@ def make_inner_step(
         with jax.named_scope("gossip"):
             params, gstate = gossip.mix(gcfg, gstate, params, step, backend)
         loss = backend.pmean_scalar(jnp.mean(losses))
-        return (params, inner, gstate, step + 1), loss
+        stats = {k: backend.pmean_scalar(jnp.mean(v)) for k, v in stats.items()}
+        return (params, inner, gstate, step + 1), (loss, stats)
 
     return step_fn
+
+
+def _with_stats(loss_fn):
+    """``loss_fn`` as a ``(loss, stats)`` function: a loss may return its
+    own counters (``{name: scalar}``) beside the loss, or the loss alone."""
+
+    def fn(params, batch):
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    return fn
 
 
 def _debias_endpoint(cfg: SlowMoConfig, state: SlowMoState) -> PyTree:
@@ -717,6 +729,8 @@ def make_slowmo_round(
         inner_mask = tp_masks.tree if (tree_inner or pack is None) else tp_masks.packed
         drift_mask = tp_masks.packed if cfg.packed else tp_masks.tree
     clip_sq_fn = base_opt.make_grad_sq_fn(backend, inner_mask)
+    # a loss that returns counters beside itself names them in ``.stats``
+    stats_names = tuple(getattr(loss_fn, "stats", ()))
     step_fn = make_inner_step(
         cfg,
         loss_fn,
@@ -743,10 +757,10 @@ def make_slowmo_round(
                 pending, new_resid = _stale_mean_start(cfg, state, backend)
 
         def body(k, acc):
-            carry, loss_sum = acc
+            carry, sums = acc
             batch_k = jax.tree.map(lambda x: x[k], batches)
-            carry, loss = step_fn(carry, batch_k, lr)
-            return carry, loss_sum + loss
+            carry, out = step_fn(carry, batch_k, lr)
+            return carry, jax.tree.map(jnp.add, sums, out)
 
         inner0, params0 = state.inner, state.params
         if tree_inner:
@@ -762,7 +776,8 @@ def make_slowmo_round(
                 count=state.inner.count,
             )
         carry0 = (params0, inner0, state.gossip, state.step)
-        acc0 = (carry0, jnp.zeros((), jnp.float32))
+        zero = jnp.zeros((), jnp.float32)
+        acc0 = (carry0, (zero, {name: zero for name in stats_names}))
         if cfg.unroll_inner:
             acc = acc0
             for k in range(cfg.tau):
@@ -794,7 +809,10 @@ def make_slowmo_round(
             boundary_mask=state.boundary_mask,
             residual=new_resid,
         )
+        loss_sum, stats_sum = loss_sum
         metrics = {"loss": loss_sum / cfg.tau}
+        if stats_sum:
+            metrics["stats"] = {k: v / cfg.tau for k, v in stats_sum.items()}
         if cfg.track_drift:
             # mean drift ||x^(i) - x_bar||^2: the per-worker sum of squares
             # goes through the leaf-aware sq_fn so that on tensor-parallel

@@ -248,9 +248,9 @@ def build_spmd_round(
         layout, state, exact_average=cfg.exact_average
     )
     batch_specs = sharding.spmd_batch_specs(layout, batches)
-    metric_specs = {"loss": P()}
-    if cfg.track_drift:
-        metric_specs["drift"] = P()
+    # every metric (loss, drift, the model's counters) is a replicated
+    # scalar: one spec, a prefix of whatever the round returns
+    metric_specs = P()
     in_specs = (state_specs, batch_specs, P())
     if cfg.masked_average:
         # the (W,) participation mask is a fourth traced input, sharded over

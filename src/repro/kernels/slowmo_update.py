@@ -71,6 +71,9 @@ def slowmo_update_2d(
             jax.ShapeDtypeStruct((rows, cols), jnp.float32),
             jax.ShapeDtypeStruct((rows, cols), jnp.float32),
         ],
+        # x0 -> x_new and u -> u_new in place: a donated state is updated
+        # where it lies, with no copy of either kept live for the round
+        input_output_aliases={1: 0, 3: 1},
         interpret=interpret,
         name="slowmo_update",
     )(gamma2d, x0, x_tau, u)

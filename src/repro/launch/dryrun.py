@@ -257,7 +257,6 @@ def main():
     p.add_argument("--noaverage", action="store_true", help="SlowMo-noaverage variant (paper §6)")
     p.add_argument("--all", action="store_true")
     p.add_argument("--rolled", action="store_true", help="keep loops rolled (fast compile; coherence-only pass)")
-    p.add_argument("--moe-dispatch", default=None, choices=["onehot_ec", "compact"])
     p.add_argument("--chunk-size", type=int, default=None, help="override xlstm chunk")
     p.add_argument("--attn-chunk", type=int, default=None)
     p.add_argument("--avg-dtype", default=None, choices=["bf16"], help="boundary all-reduce dtype")
@@ -281,8 +280,6 @@ def main():
         print(f"=== {arch} x {shape_name} [{args.mesh}/{args.layout}] ===", flush=True)
         try:
             overrides = {}
-            if args.moe_dispatch:
-                overrides["moe_dispatch"] = args.moe_dispatch
             if args.chunk_size:
                 overrides["chunk_size"] = args.chunk_size
             if args.attn_chunk:

@@ -1,9 +1,11 @@
 """Training launcher: pick an architecture + SlowMo algorithm and train.
 
 By default it runs the REDUCED configs; ``--full`` takes the published
-widths, and ``--layers N`` cuts a full config's depth to what the chips
-hold.  On a TPU the fused Pallas kernels (lines 7-8, the Nesterov inner
-step) run compiled; elsewhere the same math runs as XLA elementwise ops.
+widths, and ``--layers N``, ``--experts-held N`` (MoE) and ``--vocab N``
+cut a full config's depth, experts and vocabulary to what the chips hold.
+On a TPU the fused Pallas kernels (lines 7-8, the Nesterov inner step, the
+expert layer's grouped products) run compiled; elsewhere the same math
+runs as XLA ops.
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --algo sgp+slowmo \
         --rounds 20 --workers 8 --tau 12
@@ -50,6 +52,19 @@ def build_parser() -> argparse.ArgumentParser:
         default=None,
         help="with --full: keep the published widths and cut the depth to "
         "this many layers",
+    )
+    ap.add_argument(
+        "--experts-held",
+        type=int,
+        default=None,
+        help="with --full, MoE: each layer holds experts 0..N-1, one chip's "
+        "share under expert parallelism; the router still scores them all",
+    )
+    ap.add_argument(
+        "--vocab",
+        type=int,
+        default=None,
+        help="with --full: keep this many token ids, a slice of the vocabulary",
     )
     ap.add_argument(
         "--packed",
@@ -172,10 +187,17 @@ def build_trainer(args) -> Trainer:
         print(f"mesh path ({args.layout}): {args.workers} workers over {layout.mesh}")
 
     cfg = get_config(args.arch, reduced=not args.full)
-    if args.layers is not None:
+    cuts = {"--layers": ("n_layers", args.layers),
+            "--experts-held": ("experts_held", args.experts_held),
+            "--vocab": ("vocab_size", args.vocab)}
+    for flag, (field, value) in cuts.items():
+        if value is None:
+            continue
         if not args.full:
-            raise SystemExit("--layers cuts a --full config's depth")
-        cfg = cfg.replace(n_layers=args.layers)
+            raise SystemExit(f"{flag} cuts a --full config")
+        if field == "experts_held" and not 0 < value <= cfg.n_experts:
+            raise SystemExit(f"--experts-held needs 1..{cfg.n_experts} experts")
+        cfg = cfg.replace(**{field: value})
     model = build_model(cfg)
     n = param_count(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     size = f"{cfg.n_layers} layers" if args.full else "reduced"
@@ -255,6 +277,14 @@ def main(argv=None):
         trainer.run(state=state, rounds=rounds)
     if trainer.smcfg.use_pallas:
         print(f"fused kernels, as traced: {tally.summary()}")
+    routed = [h for h in trainer.history if "held_share" in h]
+    if routed:
+        print(
+            "routing over the run: "
+            f"{100 * sum(h['held_share'] for h in routed) / len(routed):.2f}% of "
+            "top-k assignments on held experts; largest held expert's load "
+            f"{max(h['held_load_max'] for h in routed):.3f}x the held mean"
+        )
 
 
 if __name__ == "__main__":

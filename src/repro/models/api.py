@@ -4,6 +4,8 @@ Bundle contract (all functions pure):
 * init(key) -> params
 * loss_fn(params, batch) -> scalar  (batch: dict of arrays, no worker axis)
 * forward(params, batch) -> logits
+* loss_with_stats(params, batch) -> (scalar, {name: scalar})  (MoE: the
+  routing counters; None elsewhere)
 * init_cache(batch_size, max_len) -> cache      (decoder models only)
 * decode_step(params, cache, tokens) -> (logits, cache)
 """
@@ -28,6 +30,7 @@ class ModelBundle(NamedTuple):
     forward: Callable[[PyTree, PyTree], jnp.ndarray]
     init_cache: Optional[Callable[[int, int], PyTree]]
     decode_step: Optional[Callable[[PyTree, PyTree, jnp.ndarray], tuple]]
+    loss_with_stats: Optional[Callable[[PyTree, PyTree], tuple]] = None
 
 
 _FAMILIES = {
@@ -41,6 +44,10 @@ _FAMILIES = {
 def build_model(cfg: ModelConfig) -> ModelBundle:
     mod = _FAMILIES[cfg.family]
     has_decode = cfg.has_decode and hasattr(mod, "decode_step")
+    loss_with_stats = None
+    if hasattr(mod, "loss_with_stats"):
+        loss_with_stats = functools.partial(mod.loss_with_stats, cfg)
+        loss_with_stats.stats = mod.STATS  # the counters' names, for the round
     return ModelBundle(
         config=cfg,
         init=functools.partial(mod.init_params, cfg),
@@ -48,6 +55,7 @@ def build_model(cfg: ModelConfig) -> ModelBundle:
         forward=functools.partial(mod.forward, cfg),
         init_cache=functools.partial(mod.init_cache, cfg) if has_decode else None,
         decode_step=functools.partial(mod.decode_step, cfg) if has_decode else None,
+        loss_with_stats=loss_with_stats,
     )
 
 
@@ -60,11 +68,11 @@ def active_param_count(cfg: ModelConfig, params: PyTree) -> int:
     total = param_count(params)
     if cfg.family != "moe" or not cfg.n_experts:
         return total
-    # routed expert weights are 'wi'/'wo' under moe_blocks
+    # routed expert weights are 'wi'/'wo' under moe_blocks (the held ones)
     L_moe = cfg.n_layers - cfg.first_k_dense
     per_expert = 2 * cfg.moe_d_ff * cfg.d_model + cfg.moe_d_ff * cfg.d_model
-    routed_total = L_moe * cfg.n_experts * per_expert
-    routed_active = L_moe * cfg.top_k * per_expert
+    routed_total = L_moe * cfg.held_experts * per_expert
+    routed_active = L_moe * cfg.top_k * per_expert * cfg.held_experts // cfg.n_experts
     return total - routed_total + routed_active
 
 

@@ -72,10 +72,12 @@ def rope_frequencies(head_dim: int, theta: float):
     return theta ** (-jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (B, S, H, hd); positions: (B, S) or (S,) int32."""
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: (B, S, H, hd); positions: (B, S) or (S,) int32.  Pairs (i, i +
+    hd/2) rotate by ``freqs`` (hd/2,), by default ``rope_frequencies``."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)  # (hd/2,)
+    if freqs is None:
+        freqs = rope_frequencies(hd, theta)
     if positions.ndim == 1:
         positions = positions[None]
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, hd/2)
@@ -94,14 +96,16 @@ def _repeat_kv(k, group: int):
     return jnp.repeat(k, group, axis=2) if group > 1 else k
 
 
-def attention_full(q, k, v, *, causal, window, q_offset=0):
-    """Materialized-logits attention (O(S^2) memory) — fine for short S."""
+def attention_full(q, k, v, *, causal, window, q_offset=0, scale=None):
+    """Materialized-logits attention (O(S^2) memory) — fine for short S.
+    v's head dim may differ from q's and k's; ``scale`` defaults to
+    D**-0.5 of the q.k dim D."""
     B, Sq, Hq, D = q.shape
     Skv = k.shape[1]
     group = Hq // k.shape[2]
     kf = _repeat_kv(k, group)
     vf = _repeat_kv(v, group)
-    scale = D**-0.5
+    scale = D**-0.5 if scale is None else scale
     logits = jnp.einsum(
         "bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kf.astype(jnp.float32)
     )
@@ -117,12 +121,14 @@ def attention_full(q, k, v, *, causal, window, q_offset=0):
     return jnp.einsum("bhqk,bkhd->bqhd", p, vf.astype(jnp.float32)).astype(q.dtype)
 
 
-def attention_chunked(q, k, v, *, causal, window, chunk=1024):
+def attention_chunked(q, k, v, *, causal, window, chunk=1024, scale=None):
     """Online-softmax attention in pure XLA ops: scan over kv chunks.
 
     Memory is O(Sq * chunk) instead of O(Sq * Skv) — this is the flash
     recurrence expressed at the XLA level, used for long sequences so the
-    dry-run memory analysis reflects a production configuration.
+    dry-run memory analysis reflects a production configuration.  The chunk
+    step is checkpointed, so that the backward pass recomputes each chunk's
+    scores instead of keeping them all: O(Sq * chunk) under autodiff too.
     """
     B, Sq, Hq, D = q.shape
     Skv = k.shape[1]
@@ -132,10 +138,11 @@ def attention_chunked(q, k, v, *, causal, window, chunk=1024):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_chunks = k.shape[1] // chunk
-    scale = D**-0.5
+    Dv = v.shape[-1]
+    scale = D**-0.5 if scale is None else scale
     qf = q.astype(jnp.float32) * scale
     kc = k.reshape(B, n_chunks, chunk, k.shape[2], D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n_chunks, chunk, v.shape[2], D).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n_chunks, chunk, v.shape[2], Dv).transpose(1, 0, 2, 3, 4)
     q_offset = Skv - Sq  # align sequence ends
 
     def body(carry, inp):
@@ -160,31 +167,36 @@ def attention_chunked(q, k, v, *, causal, window, chunk=1024):
         acc_new = acc * alpha[..., None] + jnp.einsum("bhqk,bkhd->bhqd", p, vj)
         return (m_new, l_new, acc_new, j + 1), None
 
+    body = jax.checkpoint(body)
     m0 = jnp.full((B, Hq, Sq), -1e30, jnp.float32)
     l0 = jnp.zeros((B, Hq, Sq), jnp.float32)
-    acc0 = jnp.zeros((B, Hq, Sq, D), jnp.float32)
+    acc0 = jnp.zeros((B, Hq, Sq, Dv), jnp.float32)
     (m, l, acc, _), _ = jax.lax.scan(body, (m0, l0, acc0, 0), (kc, vc))
     l = jnp.where(l == 0.0, 1.0, l)
     out = (acc / l[..., None]).transpose(0, 2, 1, 3)
     return out.astype(q.dtype)
 
 
-def attention(cfg: ModelConfig, q, k, v, *, causal=None, window=None):
+def attention(cfg: ModelConfig, q, k, v, *, causal=None, window=None, scale=None):
     causal = cfg.causal if causal is None else causal
     window = cfg.window if window is None else window
     impl = cfg.attention_impl
     if impl == "auto":
         impl = "chunked" if q.shape[1] > 2048 else "xla"
     if impl == "pallas":
+        if scale is not None or v.shape[-1] != q.shape[-1]:
+            raise ValueError("the flash kernel takes one head dim and its own scale")
         from ..kernels import ops as kops
 
         return kops.attention(q, k, v, causal=causal, window=window, impl="pallas")
     if impl == "chunked":
-        return attention_chunked(q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk)
-    return attention_full(q, k, v, causal=causal, window=window)
+        return attention_chunked(
+            q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk, scale=scale
+        )
+    return attention_full(q, k, v, causal=causal, window=window, scale=scale)
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, window=None):
+def decode_attention(q, k_cache, v_cache, pos, *, window=None, scale=None):
     """Single-token attention against a cache.
 
     q: (B, 1, Hq, D); caches: (B, Smax, Hkv, D); pos: scalar index of the
@@ -195,7 +207,8 @@ def decode_attention(q, k_cache, v_cache, pos, *, window=None):
     group = Hq // k_cache.shape[2]
     kf = _repeat_kv(k_cache, group).astype(jnp.float32)
     vf = _repeat_kv(v_cache, group).astype(jnp.float32)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * D**-0.5, kf)
+    scale = D**-0.5 if scale is None else scale
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kf)
     cols = jnp.arange(Smax)[None, None, None, :]
     mask = cols <= pos
     if window is not None:

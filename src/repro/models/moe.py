@@ -1,100 +1,218 @@
-"""Fine-grained Mixture-of-Experts LM — deepseek-moe-16b and kimi-k2-1t-a32b.
-
-Routing is GShard/Switch-style capacity-based top-k with einsum dispatch and
-combine, which GSPMD shards cleanly: the expert axis of the dispatch tensors
-and the expert weights is sharded over the ``model`` mesh axis, so the
-per-expert FFN compute is expert-parallel and the combine reduction lowers to
-an all-reduce over the model axis.
+"""Fine-grained Mixture-of-Experts LM — deepseek-moe-16b, kimi-k2-1t-a32b
+and deepseek-v2-lite.
 
 Structure follows DeepSeekMoE: ``first_k_dense`` leading dense-FFN layers,
 then MoE layers with ``n_shared_experts`` always-on shared experts (merged
-into one wide FFN) plus ``n_experts`` routed experts with top-k gating and a
-load-balance auxiliary loss (Switch-style  E * sum_e f_e * p_e).
+into one wide FFN) plus ``n_experts`` routed experts.  Attention is MHA/GQA,
+or multi-head latent attention (``mla``) where ``kv_lora_rank > 0``, in the
+dense and the MoE layers alike.
+
+Routing is dropless: softmax over all E router outputs, greedy top-k, and
+every assignment to an expert the layer holds is computed.  The layer holds
+experts 0 .. H-1 (``experts_held``; all by default): under expert
+parallelism, one chip's share, while the router still scores all E.  The
+assignments to held experts are sorted by expert into groups padded to
+whole tiles (``plan``) and run through grouped products: the ``expert_gmm``
+Pallas kernel on a TPU, XLA's ``ragged_dot`` elsewhere.  The balance loss is
+Switch-style (E sum_e f_e p_e over top-1 picks, in the loss) or, with
+``moe_aux="seq"``, DeepSeek-V2's per-sequence loss, which (as the published
+``AddAuxiliaryLoss`` does) enters the gradient but not the loss value.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from . import common, dense, tp
+from . import common, mla
 
 PyTree = Any
 
+# rows of a grouped-product tile: each held expert's rows pad to a multiple
+TILE_ROWS = 128
+
 
 # ---------------------------------------------------------------------------
-# router + dispatch
+# router
 # ---------------------------------------------------------------------------
 
-def capacity(cfg: ModelConfig, group_tokens: int) -> int:
-    c = int(group_tokens * cfg.top_k * cfg.capacity_factor / cfg.n_experts)
-    return max(4, ((c + 3) // 4) * 4)
-
-
-def route(cfg: ModelConfig, router_w, x_grouped):
-    """x_grouped: (G, Sg, d). Returns (combine (G,Sg,E,C) f32, aux loss)."""
-    G, Sg, d = x_grouped.shape
+def route(cfg: ModelConfig, router_w, x):
+    """x: (B, S, d). Returns the top-k experts (B, S, k) int32, their gates
+    (B, S, k) f32 and the balance loss (before ``aux_loss_coef``)."""
+    B, S, _ = x.shape
     E, k = cfg.n_experts, cfg.top_k
-    C = capacity(cfg, Sg)
-    logits = (x_grouped.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # (G, Sg, E)
-    gate_vals, idx = jax.lax.top_k(probs, k)  # (G, Sg, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)  # (B, S, E)
+    gates, idx = jax.lax.top_k(probs, k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.moe_aux == "seq":
+        # per sequence: each expert's share of the picks over its balanced
+        # share, times its mean probability; mean over sequences
+        picks = jax.nn.one_hot(idx, E, dtype=jnp.float32).sum(axis=(1, 2))  # (B, E)
+        ce = picks / (S * k / E)
+        aux = jnp.mean(jnp.sum(ce * jnp.mean(probs, axis=1), axis=-1))
+    else:
+        top1 = jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32)
+        aux = E * jnp.sum(jnp.mean(top1, axis=(0, 1)) * jnp.mean(probs, axis=(0, 1)))
+    return idx.astype(jnp.int32), gates, aux
 
-    # choice-major priority: all top-1 assignments beat any top-2 assignment
-    onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)  # (G, Sg, k, E)
-    flat = onehot.transpose(0, 2, 1, 3).reshape(G, k * Sg, E)
-    pos = jnp.cumsum(flat, axis=1) - flat  # position within expert queue
-    keep = (pos < C) * flat  # (G, kSg, E)
-    pos = pos.reshape(G, k, Sg, E).transpose(0, 2, 1, 3)  # (G, Sg, k, E)
-    keep = keep.reshape(G, k, Sg, E).transpose(0, 2, 1, 3)
-    if cfg.moe_dispatch == "compact":
-        # §Perf optimization: each (token, choice) has exactly ONE expert, so
-        # the slot one-hot does not need an E axis — (G,Sg,k,C) instead of
-        # (G,Sg,k,E,C), an E-fold cut in dispatch-tensor traffic.
-        pos_sel = jnp.sum(pos * onehot, axis=-1)  # (G, Sg, k)
-        keep_sel = jnp.sum(keep, axis=-1)  # (G, Sg, k) in {0,1}
-        slot_sel = jax.nn.one_hot(pos_sel.astype(jnp.int32), C, dtype=jnp.float32)
-        combine = jnp.einsum(
-            "gske,gsk,gskc->gsec", keep, gate_vals * keep_sel, slot_sel
-        )
-    else:  # 'onehot_ec': the naive GShard formulation (baseline)
-        slot_oh = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=jnp.float32)
-        combine = jnp.einsum(
-            "gske,gsk,gskec->gsec", keep, gate_vals, slot_oh * keep[..., None]
-        )
 
-    # load-balance aux (Switch): E * sum_e f_e * p_e  with f_e from top-1
-    top1 = onehot[:, :, 0, :]  # (G, Sg, E)
-    f_e = jnp.mean(top1, axis=(0, 1))
-    p_e = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(f_e * p_e)
-    return combine, aux
+# ---------------------------------------------------------------------------
+# dropless grouped dispatch over the held experts
+# ---------------------------------------------------------------------------
+
+class Plan(NamedTuple):
+    """Where each top-k assignment of T tokens sits among M sorted rows.
+
+    ``slot`` (T, k): its row (0, a row of an active tile, for assignments
+    to experts not held, whose ``held`` is False); ``src`` (M,): each row's
+    token (T for padding and inactive rows); ``tile_group`` (M / tm,): each
+    tile's expert; ``n_tiles``: the active tiles; ``group_rows`` (H,): each
+    expert's rows with padding; ``counts`` (H,): each expert's assignments.
+    """
+
+    slot: jax.Array
+    held: jax.Array
+    src: jax.Array
+    tile_group: jax.Array
+    n_tiles: jax.Array
+    group_rows: jax.Array
+    counts: jax.Array
+
+
+def plan(idx, held_experts: int, tm: int = TILE_ROWS) -> Plan:
+    """Sort the assignments ``idx`` (T, k) to experts 0 .. H-1 by expert,
+    each expert's rows padded to whole tiles of ``tm`` and every expert
+    given at least one tile.  M bounds the rows of any routing:
+    T * min(k, H) assignments plus a tile of padding per expert."""
+    T, k = idx.shape
+    H = held_experts
+    M = (-(-T * min(k, H) // tm) + H) * tm
+    e = idx.reshape(-1)
+    held = e < H
+    key = jnp.where(held, e, H)
+    onehot = jax.nn.one_hot(key, H + 1, dtype=jnp.int32)  # (T k, H + 1)
+    counts = onehot.sum(0)[:H]
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=-1)
+    tiles = jnp.maximum(1, -(-counts // tm))
+    starts = jnp.cumsum(tiles * tm) - tiles * tm
+    dest = jnp.where(held, starts[jnp.minimum(key, H - 1)] + rank, M)
+    src = jnp.full((M,), T, jnp.int32).at[dest].set(
+        jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop"
+    )
+    tile_end = jnp.cumsum(tiles)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(M // tm), side="right"), H - 1
+    ).astype(jnp.int32)
+    return Plan(
+        slot=jnp.where(held, dest, 0).reshape(T, k).astype(jnp.int32),
+        held=held.reshape(T, k),
+        src=src,
+        tile_group=tile_group,
+        n_tiles=tile_end[-1].astype(jnp.int32),
+        group_rows=(tiles * tm).astype(jnp.int32),
+        counts=counts,
+    )
+
+
+@jax.custom_vjp
+def dispatch(x, p: Plan):
+    """The rows (M, d): token ``src[m]``'s row of x (T, d), zero for T."""
+    return jnp.concatenate([x, jnp.zeros_like(x[:1])])[p.src]
+
+
+def _dispatch_fwd(x, p):
+    return dispatch(x, p), p
+
+
+def _dispatch_bwd(p, g):
+    # each token gathers its held assignments' rows: no scatter, and rows
+    # past the active tiles (undefined) are never read
+    dx = jnp.sum(jnp.where(p.held[..., None], g[p.slot], 0), axis=1)
+    return dx, None
+
+
+dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine(ys, w, p: Plan):
+    """(T, d): each token's sum over its assignments of ``w`` (T, k) times
+    its row of ys (M, d); ``w`` is zero where the expert is not held."""
+    return jnp.einsum("tk,tkd->td", w, ys[p.slot].astype(jnp.float32)).astype(ys.dtype)
+
+
+def _combine_fwd(ys, w, p):
+    return combine(ys, w, p), (ys, w, p)
+
+
+def _combine_bwd(res, g):
+    ys, w, p = res
+    gf = g.astype(jnp.float32)
+    w_row = jnp.zeros((ys.shape[0],), jnp.float32).at[
+        jnp.where(p.held, p.slot, ys.shape[0]).reshape(-1)
+    ].set(w.reshape(-1), mode="drop")
+    g_ext = jnp.concatenate([gf, jnp.zeros_like(gf[:1])])
+    dys = (w_row[:, None] * g_ext[p.src]).astype(ys.dtype)
+    dw = jnp.einsum("td,tkd->tk", gf, ys[p.slot].astype(jnp.float32))
+    return dys, jnp.where(p.held, dw, 0.0), None
+
+
+combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def grouped_matmul(rows, w, p: Plan):
+    """rows (M, K) times their expert's w (H, K, N), over the active tiles:
+    the ``expert_gmm`` kernel on a TPU, ``ragged_dot`` elsewhere (zero rows
+    past the groups)."""
+    from ..kernels import ops as kops
+
+    if not kops._interpret():  # on a TPU
+        from ..kernels.expert_gmm import expert_gmm
+
+        return expert_gmm(rows, w.astype(rows.dtype), p.tile_group, p.n_tiles,
+                          int(rows.shape[0] // p.tile_group.shape[0]))
+    return jax.lax.ragged_dot(rows, w.astype(rows.dtype), p.group_rows)
+
+
+STATS = ("held_share", "held_load_max")
+
+
+def routing_stats(p: Plan, top_k: int) -> dict:
+    """The share of top-k assignments that landed on held experts, and the
+    largest held expert's assignments over the held experts' mean."""
+    counts = p.counts.astype(jnp.float32)
+    T = p.slot.shape[0]
+    return {
+        "held_share": jnp.sum(counts) / (T * top_k),
+        "held_load_max": jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
+    }
 
 
 def moe_ffn(cfg: ModelConfig, p, x):
-    """x: (B, S, d). Routed experts + shared experts. Returns (out, aux)."""
+    """x: (B, S, d). The held routed experts' part of sum_e g_e FFN_e(x),
+    dropping no token, plus the shared experts.  Returns (out, aux, stats)."""
     B, S, d = x.shape
-    Sg = min(cfg.moe_group_size, B * S)
-    assert (B * S) % Sg == 0, (B, S, Sg)
-    G = (B * S) // Sg
-    xg = x.reshape(G, Sg, d)
-    combine, aux = route(cfg, p["router"], xg)
-    dispatch = (combine > 0).astype(cfg.dtype)
-    expert_in = jnp.einsum("gsec,gsd->gecd", dispatch, xg.astype(cfg.dtype))
-    h = jnp.einsum("gecd,edf->gecf", expert_in, p["wi"].astype(cfg.dtype))
-    gate, up = jnp.split(h, 2, axis=-1)
-    h = jax.nn.silu(gate.astype(jnp.float32)).astype(cfg.dtype) * up
-    expert_out = jnp.einsum("gecf,efd->gecd", h, p["wo"].astype(cfg.dtype))
-    out = jnp.einsum(
-        "gsec,gecd->gsd", combine.astype(cfg.dtype), expert_out
-    ).reshape(B, S, d)
+    dt = cfg.dtype
+    with jax.named_scope("moe_route"):
+        idx, gates, aux = route(cfg, p["router"], x)
+        pl = plan(idx.reshape(B * S, -1), cfg.held_experts)
+        w = jnp.where(pl.held, gates.reshape(B * S, -1), 0.0)
+    with jax.named_scope("moe_experts"):
+        rows = dispatch(x.reshape(B * S, d).astype(dt), pl)
+        h = grouped_matmul(rows, p["wi"], pl)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = jax.nn.silu(gate.astype(jnp.float32)).astype(dt) * up
+        out = combine(grouped_matmul(h, p["wo"], pl), w, pl).reshape(B, S, d)
     if cfg.n_shared_experts:
-        out = out + common.mlp(cfg, p["shared"], x)
-    return out, aux
+        with jax.named_scope("moe_shared"):
+            out = out + common.mlp(cfg, p["shared"], x)
+    return out, aux, routing_stats(pl, cfg.top_k)
 
 
 # ---------------------------------------------------------------------------
@@ -106,14 +224,15 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
     L_dense = cfg.first_k_dense
     L_moe = cfg.n_layers - L_dense
     d, E, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    H = cfg.held_experts
 
     def moe_block_params(k):
         ks = jax.random.split(k, 5)
         p = {
-            "attn": common.init_attn(cfg, ks[0], layers=L_moe),
+            "attn": _init_attn(cfg, ks[0], L_moe),
             "router": common.dense_init(ks[1], (L_moe, d, E)),
-            "wi": common.dense_init(ks[2], (L_moe, E, d, 2 * f)),
-            "wo": common.dense_init(ks[3], (L_moe, E, f, d)),
+            "wi": common.dense_init(ks[2], (L_moe, H, d, 2 * f)),
+            "wo": common.dense_init(ks[3], (L_moe, H, f, d)),
             "ln1": jnp.zeros((L_moe, d), jnp.float32),
             "ln2": jnp.zeros((L_moe, d), jnp.float32),
         }
@@ -130,10 +249,9 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
 
     params = {"moe_blocks": moe_block_params(keys[0])}
     if L_dense:
-        dense_cfg = cfg.replace(d_ff=cfg.dense_d_ff or cfg.d_ff)
         params["dense_blocks"] = {
-            "attn": common.init_attn(dense_cfg, keys[1], layers=L_dense),
-            "mlp": common.init_mlp(dense_cfg, keys[2], layers=L_dense),
+            "attn": _init_attn(cfg, keys[1], L_dense),
+            "mlp": common.init_mlp(_dense_cfg(cfg), keys[2], layers=L_dense),
             "ln1": jnp.zeros((L_dense, d), jnp.float32),
             "ln2": jnp.zeros((L_dense, d), jnp.float32),
         }
@@ -143,20 +261,49 @@ def init_params(cfg: ModelConfig, key) -> PyTree:
     return params
 
 
-def _moe_block(cfg: ModelConfig, x, positions, bp):
-    h = common.apply_norm(cfg, x, bp["ln1"])
-    q, k, v = common.qkv_project(cfg, bp["attn"], h, positions)
-    o = common.attention(cfg, q, k, v)
-    x = x + common.attn_out(cfg, bp["attn"], o)
+def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    return cfg.replace(d_ff=cfg.dense_d_ff or cfg.d_ff)
+
+
+def _init_attn(cfg: ModelConfig, key, layers: int):
+    if cfg.kv_lora_rank:
+        return mla.init_mla(cfg, key, layers=layers)
+    return common.init_attn(cfg, key, layers=layers)
+
+
+def _qkv(cfg: ModelConfig, p, h, positions):
+    """q, k, v of the layer's attention kind, and its softmax scale."""
+    if cfg.kv_lora_rank:
+        return (*mla.mla_qkv(cfg, p, h, positions), mla.softmax_scale(cfg))
+    return (*common.qkv_project(cfg, p, h, positions), None)
+
+
+def _attention(cfg: ModelConfig, p, h, positions):
+    """The attention sublayer (latent where ``kv_lora_rank > 0``)."""
+    scope = jax.named_scope("mla") if cfg.kv_lora_rank else contextlib.nullcontext()
+    with scope:
+        q, k, v, scale = _qkv(cfg, p, h, positions)
+        return common.attn_out(cfg, p, common.attention(cfg, q, k, v, scale=scale))
+
+
+def _dense_block(cfg: ModelConfig, x, positions, bp):
+    x = x + _attention(cfg, bp["attn"], common.apply_norm(cfg, x, bp["ln1"]), positions)
     h = common.apply_norm(cfg, x, bp["ln2"])
-    ff, aux = moe_ffn(cfg, bp, h)
-    return x + ff, aux
+    return x + common.mlp(_dense_cfg(cfg), bp["mlp"], h)
+
+
+def _moe_block(cfg: ModelConfig, x, positions, bp):
+    x = x + _attention(cfg, bp["attn"], common.apply_norm(cfg, x, bp["ln1"]), positions)
+    h = common.apply_norm(cfg, x, bp["ln2"])
+    ff, aux, stats = moe_ffn(cfg, bp, h)
+    return x + ff, (aux, stats)
 
 
 def backbone(cfg: ModelConfig, params, x, positions):
+    """Returns (x, the balance losses summed over layers, routing stats:
+    the held share's mean and the largest held load's max over layers)."""
     if cfg.first_k_dense:
-        dense_cfg = cfg.replace(d_ff=cfg.dense_d_ff or cfg.d_ff)
-        block = functools.partial(dense._block, dense_cfg, tp.IDENTITY)
+        block = functools.partial(_dense_block, cfg)
         if cfg.remat:
             block = jax.checkpoint(block)
 
@@ -170,26 +317,41 @@ def backbone(cfg: ModelConfig, params, x, positions):
         block = jax.checkpoint(block)
 
     def body(carry, bp):
-        y, aux = block(carry, positions, bp)
-        return y, aux
+        return block(carry, positions, bp)
 
-    x, auxs = jax.lax.scan(body, x, params["moe_blocks"], unroll=cfg.unroll_layers)
+    x, (auxs, stats) = jax.lax.scan(body, x, params["moe_blocks"], unroll=cfg.unroll_layers)
     x = common.apply_norm(cfg, x, params["final_norm"])
-    return x, jnp.sum(auxs)
+    stats = {"held_share": jnp.mean(stats["held_share"]),
+             "held_load_max": jnp.max(stats["held_load_max"])}
+    return x, jnp.sum(auxs), stats
+
+
+def _forward(cfg: ModelConfig, params, batch, last_only: bool = False):
+    x = params["embed"][batch["tokens"]].astype(cfg.dtype)
+    positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
+    x, aux, stats = backbone(cfg, params, x, positions)
+    if last_only:
+        x = x[:, -1:]
+    return x @ params["lm_head"].astype(x.dtype), aux, stats
 
 
 def forward(cfg: ModelConfig, params, batch, last_only: bool = False):
-    x = params["embed"][batch["tokens"]].astype(cfg.dtype)
-    positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
-    x, aux = backbone(cfg, params, x, positions)
-    if last_only:
-        x = x[:, -1:]
-    return x @ params["lm_head"].astype(x.dtype), aux
+    """(logits, balance loss)."""
+    logits, aux, _ = _forward(cfg, params, batch, last_only)
+    return logits, aux
+
+
+def loss_with_stats(cfg: ModelConfig, params, batch):
+    """(loss, routing stats); the trainer's round returns the stats."""
+    logits, aux, stats = _forward(cfg, params, batch)
+    ce = common.next_token_loss(logits, batch["tokens"])
+    if cfg.moe_aux == "seq":  # in the gradient, not in the value
+        aux = aux - jax.lax.stop_gradient(aux)
+    return ce + cfg.aux_loss_coef * aux, stats
 
 
 def loss_fn(cfg: ModelConfig, params, batch):
-    logits, aux = forward(cfg, params, batch)
-    return common.next_token_loss(logits, batch["tokens"]) + cfg.aux_loss_coef * aux
+    return loss_with_stats(cfg, params, batch)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -197,27 +359,31 @@ def loss_fn(cfg: ModelConfig, params, batch):
 # ---------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch_size: int, max_len: int) -> PyTree:
-    hd = cfg.resolved_head_dim
-    shape = lambda L: (L, batch_size, max_len, cfg.n_kv_heads, hd)  # noqa: E731
+    """Per-head k and v of every layer (latent attention: k of dn + dr and
+    v of dv, not the compressed latent)."""
+    if cfg.kv_lora_rank:
+        dk, dv = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim
+    else:
+        dk = dv = cfg.resolved_head_dim
+    shape = lambda L, w: (L, batch_size, max_len, cfg.n_kv_heads, w)  # noqa: E731
+    L_moe = cfg.n_layers - cfg.first_k_dense
     cache = {
-        "k_moe": jnp.zeros(shape(cfg.n_layers - cfg.first_k_dense), cfg.dtype),
-        "v_moe": jnp.zeros(shape(cfg.n_layers - cfg.first_k_dense), cfg.dtype),
+        "k_moe": jnp.zeros(shape(L_moe, dk), cfg.dtype),
+        "v_moe": jnp.zeros(shape(L_moe, dv), cfg.dtype),
         "pos": jnp.zeros((), jnp.int32),
     }
     if cfg.first_k_dense:
-        cache["k_dense"] = jnp.zeros(shape(cfg.first_k_dense), cfg.dtype)
-        cache["v_dense"] = jnp.zeros(shape(cfg.first_k_dense), cfg.dtype)
+        cache["k_dense"] = jnp.zeros(shape(cfg.first_k_dense, dk), cfg.dtype)
+        cache["v_dense"] = jnp.zeros(shape(cfg.first_k_dense, dv), cfg.dtype)
     return cache
 
 
-def _decode_moe_ffn(cfg: ModelConfig, bp, x):
-    """Decode-time MoE: reuse the dispatch-einsum path with one group of B
-    tokens (keeps expert weights sharded in place — no per-token weight
-    gathers, which would materialize (B, k, d, f) slices of the expert
-    weights)."""
-    B, S, d = x.shape  # S == 1
-    ff, _ = moe_ffn(cfg.replace(moe_group_size=B * S), bp, x)
-    return ff
+def _decode_attention(cfg: ModelConfig, p, h, positions, kc, vc, pos):
+    q, k, v, scale = _qkv(cfg, p, h, positions)
+    kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=1)
+    vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=1)
+    o = common.decode_attention(q, kc, vc, pos, scale=scale)
+    return common.attn_out(cfg, p, o), kc, vc
 
 
 def decode_step(cfg: ModelConfig, params, cache, tokens):
@@ -225,49 +391,30 @@ def decode_step(cfg: ModelConfig, params, cache, tokens):
     pos = cache["pos"]
     positions = jnp.full(tokens.shape, pos, jnp.int32)
 
-    if cfg.first_k_dense:
-        dense_cfg = cfg.replace(d_ff=cfg.dense_d_ff or cfg.d_ff)
-
-        def dbody(carry, layer):
-            x = carry
+    def layer(ffn):
+        def body(carry, layer):
             bp, kc, vc = layer
-            h = common.apply_norm(dense_cfg, x, bp["ln1"])
-            q, k, v = common.qkv_project(dense_cfg, bp["attn"], h, positions)
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=1)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=1)
-            o = common.decode_attention(q, kc, vc, pos)
-            x = x + common.attn_out(dense_cfg, bp["attn"], o)
-            h = common.apply_norm(dense_cfg, x, bp["ln2"])
-            x = x + common.mlp(dense_cfg, bp["mlp"], h)
-            return x, (kc, vc)
+            h = common.apply_norm(cfg, carry, bp["ln1"])
+            o, kc, vc = _decode_attention(cfg, bp["attn"], h, positions, kc, vc, pos)
+            x = carry + o
+            return x + ffn(bp, common.apply_norm(cfg, x, bp["ln2"])), (kc, vc)
 
+        return body
+
+    new_cache = dict(cache, pos=pos + 1)
+    if cfg.first_k_dense:
+        dense = layer(lambda bp, h: common.mlp(_dense_cfg(cfg), bp["mlp"], h))
         x, (kd, vd) = jax.lax.scan(
-            dbody, x, (params["dense_blocks"], cache["k_dense"], cache["v_dense"]),
+            dense, x, (params["dense_blocks"], cache["k_dense"], cache["v_dense"]),
             unroll=cfg.unroll_layers,
         )
-    else:
-        kd = vd = None
-
-    def body(carry, layer):
-        x = carry
-        bp, kc, vc = layer
-        h = common.apply_norm(cfg, x, bp["ln1"])
-        q, k, v = common.qkv_project(cfg, bp["attn"], h, positions)
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=1)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=1)
-        o = common.decode_attention(q, kc, vc, pos)
-        x = x + common.attn_out(cfg, bp["attn"], o)
-        h = common.apply_norm(cfg, x, bp["ln2"])
-        x = x + _decode_moe_ffn(cfg, bp, h)
-        return x, (kc, vc)
-
+        new_cache.update(k_dense=kd, v_dense=vd)
+    # the decode step runs the same dropless grouped layer over its B tokens
+    routed = layer(lambda bp, h: moe_ffn(cfg, bp, h)[0])
     x, (km, vm) = jax.lax.scan(
-        body, x, (params["moe_blocks"], cache["k_moe"], cache["v_moe"]),
+        routed, x, (params["moe_blocks"], cache["k_moe"], cache["v_moe"]),
         unroll=cfg.unroll_layers,
     )
+    new_cache.update(k_moe=km, v_moe=vm)
     x = common.apply_norm(cfg, x, params["final_norm"])
-    logits = x @ params["lm_head"].astype(x.dtype)
-    new_cache = dict(cache, k_moe=km, v_moe=vm, pos=pos + 1)
-    if cfg.first_k_dense:
-        new_cache.update(k_dense=kd, v_dense=vd)
-    return logits, new_cache
+    return x @ params["lm_head"].astype(x.dtype), new_cache

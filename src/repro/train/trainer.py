@@ -133,7 +133,7 @@ class Trainer:
                     "hierarchical layout (each worker's batch is split across "
                     "its pod's devices)"
                 )
-            loss_fn = model.loss_fn
+            loss_fn = getattr(model, "loss_with_stats", None) or model.loss_fn
             if getattr(layout, "model_shard", 1) > 1:
                 # tensor-parallel workers: the loss must run its matmuls on
                 # local model shards with psum over 'model' — swap in the
@@ -143,7 +143,7 @@ class Trainer:
                 loss_fn = tp_lib.make_tp_loss(model.config)
             self._loss_fn = loss_fn
         else:
-            self._loss_fn = model.loss_fn
+            self._loss_fn = getattr(model, "loss_with_stats", None) or model.loss_fn
         self.round_fn = self._build_round(self.smcfg, layout)
         self.history: list[dict] = []
 
@@ -267,6 +267,8 @@ class Trainer:
                 }
                 if "drift" in metrics:
                     rec["drift"] = float(metrics["drift"])
+                for name, value in metrics.get("stats", {}).items():
+                    rec[name] = float(value)
             if self.eval_fn and (r % max(self.tc.log_every, 1) == 0 or last):
                 with _span("eval"):
                     rec["eval"] = float(

@@ -18,6 +18,7 @@ ASSIGNED = {
     "olmo-1b": (16, 2048, 16, 16, 50304),
     "chameleon-34b": (48, 8192, 64, 8, 65536),
     "qwen3-4b": (36, 2560, 32, 8, 151936),
+    "deepseek-v2-lite": (27, 2048, 16, 16, 102400),
 }
 
 
@@ -38,6 +39,12 @@ class TestAssignedSpecs:
         assert (k.n_experts, k.top_k, k.moe_d_ff) == (384, 8, 2048)
         d = get_config("deepseek-moe-16b")
         assert (d.n_experts, d.top_k, d.n_shared_experts) == (64, 6, 2)
+        v2 = get_config("deepseek-v2-lite")
+        assert (v2.n_experts, v2.top_k, v2.n_shared_experts, v2.moe_d_ff) == (64, 6, 2, 1408)
+        assert (v2.kv_lora_rank, v2.qk_nope_head_dim, v2.qk_rope_head_dim,
+                v2.v_head_dim) == (512, 128, 64, 128)
+        assert not v2.norm_topk_prob and v2.moe_aux == "seq"
+        assert (v2.yarn.factor, v2.yarn.original_max_position) == (40.0, 4096)
 
     def test_feature_flags(self):
         assert get_config("qwen3-8b").qk_norm
@@ -78,7 +85,7 @@ class TestInputShapes:
         assert t["decode_32k"].kind == "decode"
 
     def test_all_configs_loads_ten(self):
-        assert len(all_configs()) == 10
+        assert len(all_configs()) == len(ASSIGNED) == 11
 
     def test_sub_quadratic_flags(self):
         assert get_config("xlstm-1.3b").sub_quadratic

@@ -55,16 +55,12 @@ class TestDecodeConsistency:
             ("qwen2-7b", 2e-4),          # GQA + qkv bias
             ("recurrentgemma-2b", 5e-4), # RG-LRU + local attention
             ("xlstm-1.3b", 5e-4),        # chunkwise mLSTM vs recurrent step
-            ("deepseek-moe-16b", 5e-3),  # MoE (capacity semantics differ)
+            ("deepseek-moe-16b", 5e-3),  # MoE, dropless in both paths
+            ("deepseek-v2-lite", 5e-3),  # latent attention + dropless MoE
         ],
     )
     def test_decode_matches_forward(self, arch, atol):
         cfg = get_config(arch, reduced=True)
-        if cfg.family == "moe":
-            # capacity drops depend on the dispatch group size, which differs
-            # between train (moe_group_size) and decode (B tokens); a large
-            # capacity factor removes drops so the two paths agree exactly.
-            cfg = cfg.replace(capacity_factor=8.0)
         m = build_model(cfg)
         params = m.init(jax.random.PRNGKey(0))
         B, S = 2, 12
@@ -167,29 +163,13 @@ class TestMoERouting:
     def _cfg(self):
         return get_config("deepseek-moe-16b", reduced=True)
 
-    def test_capacity_respected(self):
-        cfg = self._cfg()
-        G, Sg, d = 2, 64, cfg.d_model
-        router = jax.random.normal(jax.random.PRNGKey(0), (d, cfg.n_experts))
-        x = jax.random.normal(jax.random.PRNGKey(1), (G, Sg, d))
-        combine, aux = moe_mod.route(cfg, router, x)
-        C = moe_mod.capacity(cfg, Sg)
-        assert combine.shape == (G, Sg, cfg.n_experts, C)
-        # each (expert, slot) holds at most one token
-        slot_usage = (combine > 0).sum(axis=1)  # (G, E, C)
-        assert int(slot_usage.max()) <= 1
-        # each token occupies at most top_k slots and weights sum <= 1
-        per_token = combine.sum(axis=(2, 3))
-        assert float(per_token.max()) <= 1.0 + 1e-5
-        assert np.isfinite(float(aux))
-
     def test_aux_loss_uniform_router_near_one(self):
         """With a uniform router, E * sum f_e p_e ~= 1 (perfectly balanced)."""
         cfg = self._cfg()
-        G, Sg, d = 1, 256, cfg.d_model
+        B, S, d = 1, 256, cfg.d_model
         router = jnp.zeros((d, cfg.n_experts))  # uniform logits
-        x = jax.random.normal(jax.random.PRNGKey(2), (G, Sg, d))
-        _, aux = moe_mod.route(cfg, router, x)
+        x = jax.random.normal(jax.random.PRNGKey(2), (B, S, d))
+        _, _, aux = moe_mod.route(cfg, router, x)
         assert abs(float(aux) - 1.0) < 0.15
 
     def test_moe_ffn_zero_router_matches_shared_only_plus_uniform(self):

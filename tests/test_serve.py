@@ -104,13 +104,11 @@ class TestDecodeEngineOracle:
             "recurrentgemma-2b",  # RG-LRU recurrence + local attention
             "xlstm-1.3b",         # mLSTM recurrent decode
             "deepseek-moe-16b",   # MoE dispatch
+            "deepseek-v2-lite",   # latent attention + dropless MoE
         ],
     )
     def test_greedy_equals_forward_argmax(self, arch):
         cfg = get_config(arch, reduced=True)
-        if cfg.family == "moe":
-            # align train/decode capacity semantics (see test_models)
-            cfg = cfg.replace(capacity_factor=8.0)
         model, params = _build(cfg)
         eng = DecodeEngine(model, params, ServeConfig(max_len=32))
         prompts = np.asarray(

@@ -251,3 +251,64 @@ def test_training_round_compiles_with_kernels(topo, no_persistent_cache, monkeyp
     c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
     assert _kernel_calls(c) >= 2
     assert _kernel_names(c) == {"fused_nesterov", "slowmo_update"}
+
+
+def test_expert_gmm_compiles_forward_and_backward(one_chip, no_persistent_cache):
+    """The grouped products of DeepSeek-V2-Lite's expert layer at published
+    widths (8 held experts, 2 x 4096 tokens, top-6, 128-row tiles): the
+    forward, and the backward's transposed product and per-expert weight
+    product, all custom calls named ``expert_gmm``."""
+    from repro.kernels import expert_gmm as eg
+
+    tm, held, d, f = 128, 8, 2048, 1408
+    rows = (8192 * 6 // tm + held) * tm
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(x, wi, wo, tile_group, n_tiles):
+        h = eg.expert_gmm(x, wi, tile_group, n_tiles, tm)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        return jnp.sum(eg.expert_gmm(h, wo, tile_group, n_tiles, tm).astype(jnp.float32))
+
+    c = _compile(
+        jax.grad(layer, (0, 1, 2)),
+        s((rows, d), jnp.bfloat16), s((held, d, 2 * f), jnp.bfloat16),
+        s((held, f, d), jnp.bfloat16), s((rows // tm,), jnp.int32), s((), jnp.int32),
+    )
+    # gate/up forward; down's lhs and weight products; gate/up's lhs and
+    # weight products (down's forward output is not needed by the gradient)
+    assert _kernel_calls(c) == 5
+    assert _kernel_names(c) == {"expert_gmm"}
+
+
+def test_moe_round_compiles_with_the_expert_kernel(topo, no_persistent_cache, monkeypatch):
+    """A packed round of the REDUCED deepseek-v2-lite (latent attention,
+    dropless MoE) on one described chip runs the expert layer through
+    ``expert_gmm`` beside the fused SlowMo kernels."""
+    from repro.configs import get_config
+    from repro.core import slowmo
+    from repro.distributed import spmd
+    from repro.kernels import ops
+    from repro.launch.mesh import WorkerLayout
+    from repro.models import build_model
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build_model(get_config("deepseek-v2-lite", reduced=True))
+    cfg = dataclasses.replace(
+        slowmo.preset("local_sgd+slowmo", num_workers=1, tau=2),
+        packed=True,
+        use_pallas=True,
+    )
+    pshape = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pack = slowmo.make_state_pack_spec(cfg, pshape)
+    state = jax.eval_shape(lambda p: slowmo.init_slowmo(cfg, p, pack=pack), pshape)
+    batches = {"tokens": jax.ShapeDtypeStruct((2, 1, 2, 64), jnp.int32)}
+    layout = WorkerLayout(
+        Mesh([topo.devices[0]], ("data",)),
+        worker_axes=("data",), batch_axes=(), model_axes=(),
+    )
+    fn = spmd.build_spmd_round(cfg, model.loss_with_stats, layout, state, batches, pack)
+    c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
+    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update", "expert_gmm"}
