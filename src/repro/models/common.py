@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
+from ..kernels import ops as kops
 
 PyTree = Any
 
@@ -178,17 +179,20 @@ def attention_chunked(q, k, v, *, causal, window, chunk=1024, scale=None):
 
 
 def attention(cfg: ModelConfig, q, k, v, *, causal=None, window=None, scale=None):
+    """``attention_impl`` 'auto' takes causal attention on a TPU through
+    the flash kernel (forward and backward), and elsewhere materialises the
+    logits up to 2048 keys and scans kv chunks past that; 'xla', 'chunked'
+    and 'pallas' pick one path outright."""
     causal = cfg.causal if causal is None else causal
     window = cfg.window if window is None else window
     impl = cfg.attention_impl
     if impl == "auto":
-        impl = "chunked" if q.shape[1] > 2048 else "xla"
+        if causal and not kops._interpret():
+            impl = "pallas"
+        else:
+            impl = "chunked" if q.shape[1] > 2048 else "xla"
     if impl == "pallas":
-        if scale is not None or v.shape[-1] != q.shape[-1]:
-            raise ValueError("the flash kernel takes one head dim and its own scale")
-        from ..kernels import ops as kops
-
-        return kops.attention(q, k, v, causal=causal, window=window, impl="pallas")
+        return kops.attention(q, k, v, causal=causal, window=window, scale=scale, impl="pallas")
     if impl == "chunked":
         return attention_chunked(
             q, k, v, causal=causal, window=window, chunk=cfg.attn_chunk, scale=scale

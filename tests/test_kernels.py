@@ -173,3 +173,48 @@ class TestFlashAttentionKernel:
         v = jnp.ones((B, S, H, D)) * 2.5
         out = fa.flash_attention(q, k, v, causal=True, interpret=True)
         np.testing.assert_allclose(np.asarray(out), 2.5 * np.ones_like(out), rtol=1e-5)
+
+    @pytest.mark.parametrize(
+        "Hq,Hkv,S,D,Dv,scale,window,dtype",
+        [
+            (4, 4, 256, 64, 64, None, None, jnp.float32),  # MHA, causal
+            (4, 2, 256, 64, 64, None, None, jnp.float32),  # GQA 2:1
+            (4, 4, 320, 64, 64, None, 96, jnp.float32),  # window across blocks
+            (4, 2, 256, 64, 64, None, 128, jnp.float32),  # GQA + window
+            (2, 2, 256, 192, 128, 0.07, None, jnp.float32),  # latent attention's dims
+            (4, 2, 200, 192, 128, 0.07, None, jnp.float32),  # not a block multiple
+            (4, 2, 200, 64, 64, None, 64, jnp.float32),  # ragged + window
+            (4, 4, 256, 64, 64, None, None, jnp.bfloat16),
+            (4, 2, 200, 192, 128, 0.07, 96, jnp.bfloat16),
+        ],
+        ids=["mha", "gqa", "window", "gqa-window", "dv128-scale", "ragged",
+             "ragged-window", "bf16", "bf16-gqa-ragged-window"],
+    )
+    def test_matches_full_attention_and_its_gradients(self, Hq, Hkv, S, D, Dv, scale,
+                                                      window, dtype):
+        """Output and d/dq, d/dk, d/dv of the kernel (128-row blocks, so
+        several q and kv blocks, masked and unmasked tiles) against float32
+        ``attention_full`` under ``highest`` precision."""
+        from repro.models.common import attention_full
+
+        q, k, v = rnd(0, (2, S, Hq, D), dtype), rnd(1, (2, S, Hkv, D), dtype), rnd(2, (2, S, Hkv, Dv), dtype)
+        w = rnd(3, (2, S, Hq, Dv))
+
+        def kernel(q, k, v):
+            o = fa.flash_attention(q, k, v, causal=True, scale=scale, window=window,
+                                   block_q=128, block_k=128, interpret=True)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+
+        def full(q, k, v):
+            f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+            o = attention_full(*f32, causal=True, window=window, scale=scale)
+            return jnp.sum(o * w), o
+
+        with jax.default_matmul_precision("highest"):
+            (_, o_k), g_k = jax.value_and_grad(kernel, (0, 1, 2), has_aux=True)(q, k, v)
+            (_, o_r), g_r = jax.value_and_grad(full, (0, 1, 2), has_aux=True)(q, k, v)
+        tol = 1e-4 if dtype == jnp.float32 else 3e-2
+        for got, want in zip((o_k, *g_k), (o_r, *g_r)):
+            assert got.dtype == dtype
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))

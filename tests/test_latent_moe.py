@@ -130,6 +130,26 @@ class TestLayers:
             cfg.replace(attention_impl="chunked", attn_chunk=16), p, h, pos)
         _close(chunked, full, 1e-5)
 
+    def test_flash_kernel_takes_the_latent_dims(self):
+        """The flash kernel (the TPU's path; interpreted here) with q.k of
+        dn + dr, v of dv and the MLA scale equals the full attention, and so
+        do the gradients of the layer's parameters through it."""
+        cfg, _, params = _setup()
+        p = _layer(params["moe_blocks"]["attn"])
+        h = jax.random.normal(jax.random.PRNGKey(5), (1, 40, cfg.d_model))
+        pos = jnp.arange(40, dtype=jnp.int32)[None]
+
+        def out(impl):
+            def f(p):
+                return moe_mod._attention(cfg.replace(attention_impl=impl), p, h, pos)
+            with jax.default_matmul_precision("highest"):
+                return f(p), jax.grad(lambda p: jnp.sum(jnp.sin(f(p))))(p)
+
+        (full, g_full), (flash, g_flash) = out("xla"), out("pallas")
+        _close(flash, full, 1e-5)
+        for a, b in zip(jax.tree.leaves(g_flash), jax.tree.leaves(g_full)):
+            _close(a, b, 1e-4)
+
     @pytest.mark.parametrize("held", [None, 2])
     def test_expert_layer_matches_reference(self, held):
         cfg, arch, params = _setup(held)

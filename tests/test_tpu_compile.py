@@ -220,10 +220,39 @@ def test_flash_attention_compiles(one_chip, no_persistent_cache, shape):
     assert _kernel_calls(c) == 1
 
 
+@pytest.mark.parametrize(
+    "B,S,H,D,Dv",
+    [(2, 4096, 16, 192, 128), (2, 2048, 16, 128, 128)],
+    ids=["deepseek-v2-lite-4096", "olmo-2048"],
+)
+def test_flash_attention_trains_at_cell_shapes(one_chip, no_persistent_cache, B, S, H, D, Dv):
+    """The flash kernel's forward and its gradient compile at the two
+    training cells' attention (bf16): the forward is one call, the gradient
+    three (forward with its log-sum-exp, dq, dk/dv), every one a custom
+    call named ``flash_attention`` whose op path ends in its name."""
+    q = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, S, H, Dv), jnp.bfloat16, sharding=one_chip)
+
+    def attend(q, k, v):
+        return flash_attention.flash_attention(q, k, v, scale=0.1)
+
+    fwd = _compile(attend, q, q, v)
+    grad = _compile(
+        jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)), (0, 1, 2)),
+        q, q, v,
+    )
+    assert _kernel_calls(fwd) == 1
+    assert _kernel_calls(grad) == 3
+    assert _kernel_names(fwd) == _kernel_names(grad) == {"flash_attention"}
+    calls = [line for line in grad.as_text().splitlines() if "tpu_custom_call" in line]
+    assert all('/flash_attention/pallas_call"' in line for line in calls), calls
+
+
 def test_training_round_compiles_with_kernels(topo, no_persistent_cache, monkeypatch):
     """A packed local-SGD + SlowMo round of the REDUCED olmo-1b, through the
-    shard_map path on one described chip, compiles with the fused kernels
-    inside (the Nesterov inner step and the lines 7-8 update)."""
+    shard_map path on one described chip, compiles with the kernels inside
+    (the Nesterov inner step, the lines 7-8 update, and causal attention
+    through the flash kernel, forward and backward)."""
     from repro.configs import get_config
     from repro.core import slowmo
     from repro.distributed import spmd
@@ -250,7 +279,7 @@ def test_training_round_compiles_with_kernels(topo, no_persistent_cache, monkeyp
     fn = spmd.build_spmd_round(cfg, model.loss_fn, layout, state, batches, pack)
     c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
     assert _kernel_calls(c) >= 2
-    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update"}
+    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update", "flash_attention"}
 
 
 def test_expert_gmm_compiles_forward_and_backward(one_chip, no_persistent_cache):
@@ -286,7 +315,9 @@ def test_expert_gmm_compiles_forward_and_backward(one_chip, no_persistent_cache)
 def test_moe_round_compiles_with_the_expert_kernel(topo, no_persistent_cache, monkeypatch):
     """A packed round of the REDUCED deepseek-v2-lite (latent attention,
     dropless MoE) on one described chip runs the expert layer through
-    ``expert_gmm`` beside the fused SlowMo kernels."""
+    ``expert_gmm`` and the latent attention through ``flash_attention``
+    beside the fused SlowMo kernels; no loop of the attention's own (the
+    chunked path's scan over kv chunks) is left under the ``mla`` scope."""
     from repro.configs import get_config
     from repro.core import slowmo
     from repro.distributed import spmd
@@ -311,4 +342,8 @@ def test_moe_round_compiles_with_the_expert_kernel(topo, no_persistent_cache, mo
     )
     fn = spmd.build_spmd_round(cfg, model.loss_with_stats, layout, state, batches, pack)
     c = fn.lower(state, batches, jax.ShapeDtypeStruct((), jnp.float32)).compile()
-    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update", "expert_gmm"}
+    hlo = c.as_text()
+    assert _kernel_names(c) == {"fused_nesterov", "slowmo_update", "expert_gmm",
+                                "flash_attention"}
+    loops = [line for line in hlo.splitlines() if " while(" in line and "body=" in line]
+    assert loops and not [line for line in loops if "/mla/" in line], loops
