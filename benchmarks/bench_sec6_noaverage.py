@@ -13,10 +13,10 @@ ALGOS = ["sgp", "sgp+slowmo", "sgp+slowmo-noaverage"]
 
 def main():
     print("# Sec 6 analog: noaverage variant (tau=12, beta=0.6)")
-    print("algorithm,final_train_loss,eval_loss,us_per_step")
+    print("algorithm,final_train_loss,eval_loss")
     for name in ALGOS:
         r = common.run_algorithm(name, common.preset_cfg(name))
-        print(f"{name},{r.final_loss:.4f},{r.eval_loss:.4f},{r.us_per_inner_step:.1f}")
+        print(f"{name},{r.final_loss:.4f},{r.eval_loss:.4f}")
 
 
 if __name__ == "__main__":

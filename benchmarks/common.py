@@ -8,16 +8,15 @@ qualitative effects (SlowMo improves each base optimizer; tau has an interior
 optimum; alpha=1 best; buffer strategies behave as in App. B.4) — not the
 absolute numbers, which are task-specific.
 
-Results are cached under artifacts/bench/ as JSON; `benchmarks.run`
-aggregates and prints the final CSV.
+Results are cached under artifacts/bench3/ as JSON; `benchmarks.run`
+aggregates and prints the final CSV.  Only losses are recorded: these runs
+compare optimizers, and speed is measured on the chip (`benchmarks/chip/`).
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 import os
-import time
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +28,7 @@ from repro.core.base_opt import InnerOptConfig
 from repro.data import MarkovLMConfig, chain_entropy, make_markov_sampler
 from repro.models import build_model
 
-CACHE_DIR = "artifacts/bench2"
+CACHE_DIR = "artifacts/bench3"
 
 # benchmark task: small-but-real transformer on a learnable Markov LM.
 # REGIME NOTE: the budget/LR put the comparison in the TRANSIENT regime
@@ -66,8 +65,6 @@ class RunResult:
     best_loss: float
     eval_loss: float
     history: list
-    wall_s: float
-    us_per_inner_step: float
 
     def as_dict(self):
         return dataclasses.asdict(self)
@@ -97,13 +94,10 @@ def run_algorithm(
 
     rounds = max(1, total_inner_steps // smcfg.tau)
     history = []
-    t0 = time.perf_counter()
     for r in range(rounds):
         batch = {"tokens": sampler(r, smcfg.tau, PER_WORKER_BATCH, SEQ)}
         state, metrics = round_fn(state, batch, lr)
         history.append(float(metrics["loss"]))
-    jax.block_until_ready(state.outer_params)
-    wall = time.perf_counter() - t0
 
     # held-out eval on the synchronized parameters
     eval_params = state.outer_params
@@ -119,8 +113,6 @@ def run_algorithm(
         best_loss=float(np.min(history)),
         eval_loss=eval_loss,
         history=history,
-        wall_s=wall,
-        us_per_inner_step=wall / (rounds * smcfg.tau) * 1e6,
     )
     os.makedirs(CACHE_DIR, exist_ok=True)
     with open(path, "w") as f:
@@ -141,7 +133,8 @@ def preset_cfg(preset: str, tau: int = 12, beta: float = 0.6, **kw) -> slowmo.Sl
 
 # ---------------------------------------------------------------------------
 # analytic communication model (Table 2 analog): bytes per inner iteration
-# per worker, N = parameter count. See EXPERIMENTS.md for the derivation.
+# per worker, N = parameter count: a ring all-reduce moves 2N per member, a
+# gossip push N; SlowMo's boundary all-reduce amortizes over tau steps.
 # ---------------------------------------------------------------------------
 
 def comm_bytes_per_step(name: str, n_params: int, tau: int, dtype_bytes: int = 2) -> float:

@@ -1,7 +1,7 @@
-"""Benchmark driver: one section per paper table/figure + the roofline report.
+"""Benchmark driver: one section per paper table/figure, comparing losses.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--fast]
-Prints CSV sections; results are cached under artifacts/bench/.  A section
+Prints CSV sections; results are cached under artifacts/bench3/.  A section
 that raises is reported and the rest still run; the script then exits
 non-zero, naming the failed sections."""
 from __future__ import annotations
@@ -28,17 +28,14 @@ def main() -> None:
         bench_b3_alphabeta,
         bench_b4_buffers,
         bench_fig3_tau,
-        bench_roofline,
         bench_sec6_noaverage,
         bench_table1,
-        bench_table2,
     )
 
     fast = "--fast" in sys.argv
     failed: list[str] = []
     sections = [
         ("Table 1: base algorithms with/without SlowMo", bench_table1.main),
-        ("Table 2: time per iteration + communication model", bench_table2.main),
     ]
     if not fast:
         sections += [
@@ -48,7 +45,6 @@ def main() -> None:
         ]
     sections += [
         ("Section 6: SlowMo-noaverage", bench_sec6_noaverage.main),
-        ("Roofline (dry-run artifacts)", bench_roofline.main),
     ]
     for title, fn in sections:
         _section(title, fn, failed)

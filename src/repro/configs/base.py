@@ -82,7 +82,7 @@ class ModelConfig:
     dtype: Any = jnp.float32
     remat: bool = False
     attention_impl: str = "auto"  # 'auto' | 'xla' | 'chunked' | 'pallas'
-    unroll_layers: bool = False  # unroll scan-over-layers (dry-run cost analysis)
+    unroll_layers: bool = False  # unroll scan-over-layers (cost analysis counts a loop once)
     attn_chunk: int = 1024  # kv-chunk for the chunked (online-softmax) impl
     source: str = ""  # citation
 

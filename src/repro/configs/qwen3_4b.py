@@ -13,7 +13,7 @@ CONFIG = ModelConfig(
     source="hf:Qwen/Qwen3-8B",
 )
 
-# sliding-window variant used only for the long_500k dry-run
+# sliding-window variant for the long_500k shape (configs.INPUT_SHAPES)
 LONG_CONTEXT = CONFIG.replace(window=4096)
 
 REDUCED = CONFIG.replace(

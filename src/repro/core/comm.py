@@ -6,7 +6,8 @@ run in two modes:
 * ``AxisBackend`` ("axis") — the oracle: the m workers are a leading array
   axis of every leaf, and collectives are plain array ops (``jnp.mean`` over
   axis 0, ``jnp.roll`` along axis 0).  Single-program, single-device; this is
-  the layout the rest of the repo (init, checkpoints, benchmarks) speaks.
+  the layout the rest of the repo (init, checkpoints, the paper-figure
+  analogs) speaks.
 
 * ``MeshBackend`` ("mesh") — the lowered path: the round body runs inside
   ``jax.shard_map`` with the worker axis sharded over one or

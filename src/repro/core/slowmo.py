@@ -82,7 +82,8 @@ class SlowMoConfig:
     # lines-7-8 outer update, AND the inner Nesterov step whenever the base
     # evaluates gradients at the params themselves (sgd+nesterov, non-gossip)
     average_dtype: Any = None  # dtype of the exact-average all-reduce (None=f32)
-    unroll_inner: bool = False  # unroll the tau inner steps (dry-run cost analysis)
+    unroll_inner: bool = False  # unroll the tau inner steps (the contract
+    # auditor's census then counts each step's collectives)
     packed: bool = False  # flat-buffer state: one kernel launch / collective
     # per boundary instead of one per leaf (see core/packing.py); requires a
     # PackSpec threaded through init_slowmo / make_slowmo_round.
@@ -655,7 +656,6 @@ def make_slowmo_round(
     loss_fn: Callable[[PyTree, PyTree], jnp.ndarray],
     backend: comm.CommBackend | None = None,
     pack: PackSpec | None = None,
-    local_tree_inner: bool | None = None,
     tp_masks: TPMasks | None = None,
 ):
     """Build the jittable round function.
@@ -690,11 +690,6 @@ def make_slowmo_round(
     there the gradients alone are packed around that sync (``grad_pack``),
     keeping it at one collective per buffer while the params stay cached.
 
-    ``local_tree_inner`` overrides that choice for the local base (None =
-    automatic, i.e. tree-carry): ``False`` forces the legacy fully-packed
-    inner loop — kept so ``bench_spmd_round.py`` can measure the
-    amortization delta; numerics are identical either way.
-
     ``tp_masks`` (required iff the backend has model shards AND clip_norm or
     track_drift is on) carries the leaf-aware sharded/replicated split both
     reductions need to span model shards correctly — built by
@@ -711,8 +706,6 @@ def make_slowmo_round(
     # (their gradient sync, if any, packs just the grads around the
     # collective), so params/momentum convert at the round boundary only.
     tree_inner = pack is not None and cfg.base == "local"
-    if local_tree_inner is not None:
-        tree_inner = tree_inner and local_tree_inner
     grad_pack = pack if (tree_inner and getattr(backend, "batch_axes", ())) else None
     tp = getattr(backend, "model_shards", 1)
     if tp > 1 and (cfg.inner.clip_norm or cfg.track_drift) and tp_masks is None:

@@ -17,19 +17,18 @@ Sharding summary (Megatron-style within each worker):
 * recurrent widths (lru, conv, gates): channel dim over model
 
 ``model_spec_tail`` is THE rule; everything else here is a consumer view of
-it: ``slowmo_state_specs`` (GSPMD dry-run), ``spmd_state_specs`` (specs for
-arrays ENTERING shard_map — all functions in this module run outside the
-mapped body), ``model_shard_dims`` (feeds ``packing.make_sharded_pack_spec``)
-and ``model_sharded_mask`` (feeds the leaf-aware TP clip/drift reductions).
-``tests/test_spec_rules.py`` pins that the dry-run and mesh views agree
-leaf-for-leaf on every preset.
+it: ``spmd_state_specs`` (specs for arrays ENTERING shard_map — all
+functions in this module run outside the mapped body), ``model_shard_dims``
+(feeds ``packing.make_sharded_pack_spec``) and ``model_sharded_mask`` (feeds
+the leaf-aware TP clip/drift reductions).  ``slowmo_state_specs`` states the
+rule field by field for a whole SlowMoState; ``tests/test_spec_rules.py``
+pins that it and ``spmd_state_specs`` agree leaf-for-leaf on every preset.
 """
 from __future__ import annotations
 
 from typing import Any
 
 import jax
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..launch.mesh import WorkerLayout
@@ -92,10 +91,9 @@ def _leaf_name(path) -> tuple[str, tuple[str, ...]]:
 def model_spec_tail(name: str, containers: tuple[str, ...], shape, model_size: int):
     """Trailing-dim PartitionSpec entries for one model-parameter leaf.
 
-    THE model-sharding rule of the repo: the GSPMD dry-run
-    (``slowmo_state_shardings`` / ``serve_param_shardings``), the shard_map
-    execution path (``spmd_state_specs``) and the tensor-parallel packing
-    (``model_shard_dims`` -> ``packing.make_sharded_pack_spec``) all derive
+    THE model-sharding rule of the repo: the shard_map execution path
+    (``spmd_state_specs``, ``serve_param_specs``) and the tensor-parallel
+    packing (``model_shard_dims`` -> ``packing.make_sharded_pack_spec``) derive
     which dim of which leaf shards over ``model`` from this one function, so
     they cannot disagree.  ``model_size <= 1`` means no tensor parallelism —
     everything replicates over (absent or size-1) model axes."""
@@ -191,16 +189,11 @@ def _wax_entry(layout: WorkerLayout):
     return (layout.worker_axes if len(layout.worker_axes) > 1 else layout.worker_axes[0],)
 
 
-def slowmo_state_specs(layout: WorkerLayout, state_shapes, *, shard_outer: bool = False) -> PyTree:
-    """PartitionSpec tree for a SlowMoState (shapes from jax.eval_shape) —
-    the GSPMD dry-run's spec rule, shared leaf-for-leaf with the shard_map
-    path (``spmd_state_specs``); ``slowmo_state_shardings`` wraps it in
-    NamedShardings.
-
-    ``shard_outer=True`` additionally ZeRO-shards the outer iterate and slow
-    momentum over the worker (data) axes — a beyond-paper optimization; the
-    paper-faithful baseline replicates them on every node.
-    """
+def slowmo_state_specs(layout: WorkerLayout, state_shapes) -> PyTree:
+    """PartitionSpec tree for a SlowMoState (shapes from jax.eval_shape),
+    written out field by field from ``model_spec_tail``: the independent
+    statement of the rule that ``spmd_state_specs`` must agree with leaf for
+    leaf (only tests reach it)."""
     M = _msize(layout)
     wax = _wax_entry(layout)
 
@@ -216,35 +209,9 @@ def slowmo_state_specs(layout: WorkerLayout, state_shapes, *, shard_outer: bool 
     outer_leaf = jax.tree.leaves(state_shapes.outer_params)
     param_leaf = jax.tree.leaves(state_shapes.params)
     noavg = outer_leaf[0].ndim == param_leaf[0].ndim
-    if noavg:
-        outer_prefix = wax
-    elif shard_outer and layout.worker_axes:
-        outer_prefix = wax  # ZeRO: shard leading (stack/first) dim... see below
-    else:
-        outer_prefix = ()
-
-    if noavg or not shard_outer or not layout.worker_axes:
-        outer_specs = _specs_for_tree(
-            state_shapes.outer_params, M, prefix=outer_prefix if noavg else ()
-        )
-    else:
-        # ZeRO outer state: shard the FIRST dim that (a) is not already
-        # model-sharded and (b) divides by the worker count.  Layer-stack
-        # leading dims (61, 36, ...) rarely divide by W=16, so scanning all
-        # dims (d_model/d_ff/vocab usually qualify) is what makes this work.
-        W = layout.num_workers
-
-        def zero_spec(path, leaf):
-            name, keys = _leaf_name(path)
-            tail = list(model_spec_tail(name, keys[:-1], leaf.shape, M))
-            for i, (slot, dim) in enumerate(zip(tail, leaf.shape)):
-                if slot is None and dim % W == 0 and dim >= W:
-                    tail[i] = wax[0]
-                    break
-            return P(*tail)
-
-        outer_specs = jax.tree_util.tree_map_with_path(zero_spec, state_shapes.outer_params)
-    u_specs = outer_specs
+    outer_specs = _specs_for_tree(
+        state_shapes.outer_params, M, prefix=wax if noavg else ()
+    )
 
     from ..core.slowmo import SlowMoState
     from ..core.base_opt import InnerOptState
@@ -266,7 +233,7 @@ def slowmo_state_specs(layout: WorkerLayout, state_shapes, *, shard_outer: bool 
             stale_w=P() if state_shapes.gossip.stale_w.ndim == 0 else gossip_w_spec,
         ),
         outer_params=outer_specs,
-        slow_u=u_specs,
+        slow_u=outer_specs,
         step=P(),
         outer_step=P(),
         # overlap_boundary double buffers: snapshot like params, anchor
@@ -292,13 +259,6 @@ def slowmo_state_specs(layout: WorkerLayout, state_shapes, *, shard_outer: bool 
     )
 
 
-def slowmo_state_shardings(layout: WorkerLayout, state_shapes, *, shard_outer: bool = False) -> PyTree:
-    """NamedSharding tree for a SlowMoState on the GSPMD (dry-run) path."""
-    mesh = layout.mesh
-    specs = slowmo_state_specs(layout, state_shapes, shard_outer=shard_outer)
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
-
-
 def spmd_state_specs(layout: WorkerLayout, state, *, exact_average: bool) -> PyTree:
     """PartitionSpec tree for a SlowMoState entering ``shard_map``.
 
@@ -310,8 +270,7 @@ def spmd_state_specs(layout: WorkerLayout, state, *, exact_average: bool) -> PyT
 
     Tensor-parallel layouts (model axes of size > 1) additionally shard the
     trailing dims of every parameter-shaped leaf via the SAME
-    ``model_spec_tail`` rules the GSPMD dry-run trusts (one rule, both
-    paths): params / momentum / gossip messages get ``P(wax, *model_tail)``,
+    ``model_spec_tail`` rules as ``slowmo_state_specs`` (one rule): params / momentum / gossip messages get ``P(wax, *model_tail)``,
     and the "replicated" outer iterate / slow momentum are replicated over
     the worker axes only — over ``model`` they stay sharded, so the outer
     update runs on the local shard.
@@ -357,8 +316,8 @@ def spmd_state_specs(layout: WorkerLayout, state, *, exact_average: bool) -> PyT
                 tree,
             )
     else:
-        # per-leaf tree layout: trailing dims via model_spec_tail (the
-        # dry-run's rule), leading worker axis over the worker mesh axes
+        # per-leaf tree layout: trailing dims via model_spec_tail, leading
+        # worker axis over the worker mesh axes
         def wtree(tree):
             return _specs_for_tree(tree, M, prefix=(wentry,))
 
@@ -408,11 +367,10 @@ def batch_partition_spec(layout: WorkerLayout, ndim: int) -> P:
     (each worker's batch) over its batch axes — on the hierarchical layout
     that is ``P(None, 'pod', 'data')``.
 
-    Single source of truth for BOTH execution paths: the GSPMD dry-run
-    (``batch_shardings``) and the shard_map mesh path (``spmd_batch_specs``)
-    wrap this one function, so they cannot disagree on which axes shard the
-    batch (they used to: the dry-run sharded B over ``data`` while the mesh
-    path replicated it).  Pinned by ``tests/test_hierarchical_spmd.py``.
+    The shard_map mesh path (``spmd_batch_specs``) and the NamedSharding
+    view (``batch_shardings``) wrap this one function, so they cannot
+    disagree on which axes shard the batch.  Pinned by
+    ``tests/test_hierarchical_spmd.py``.
     """
     entries = [None, _wax_entry(layout)[0]]
     if layout.batch_axes and ndim >= 3:
@@ -436,7 +394,7 @@ def spmd_mask_spec(layout: WorkerLayout) -> P:
 
 
 def batch_shardings(layout: WorkerLayout, batch_shapes: PyTree) -> PyTree:
-    """NamedShardings of training batches on the GSPMD (dry-run) path."""
+    """NamedShardings of training batches, from ``batch_partition_spec``."""
     mesh = layout.mesh
     return jax.tree.map(
         lambda leaf: NamedSharding(mesh, batch_partition_spec(layout, leaf.ndim)),
@@ -465,53 +423,3 @@ def serve_pool_spec(layout: WorkerLayout, pool_shape: tuple) -> P:
     if mentry is not None and hkv % M == 0 and hkv >= M:
         spec[-2] = mentry
     return P(*spec)
-
-
-def serve_param_shardings(layout: WorkerLayout, param_shapes: PyTree) -> PyTree:
-    """Serving parameters: no worker axis, model-parallel only (replicated
-    over the data axes — the serve baseline)."""
-    mesh = layout.mesh
-    specs = _specs_for_tree(param_shapes, _msize(layout), prefix=())
-    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs)
-
-
-def serve_cache_shardings(layout: WorkerLayout, cache_shapes: PyTree, batch_size: int) -> PyTree:
-    """KV / recurrent caches: shard the batch dim over the data axes (when
-    divisible) and the trailing dim over model (when divisible)."""
-    mesh = layout.mesh
-    M = _msize(layout)
-    dax = layout.data_axes
-    D = int(np.prod([mesh.shape[a] for a in dax]))
-    dentry = dax if len(dax) > 1 else (dax[0] if dax else None)
-
-    def one(path, leaf):
-        if leaf.ndim == 0:
-            return NamedSharding(mesh, P())
-        spec = [None] * leaf.ndim
-        # find the batch dim: the first dim equal to batch_size
-        for i, d in enumerate(leaf.shape):
-            if d == batch_size and batch_size % D == 0 and D > 1:
-                spec[i] = dentry
-                break
-        if leaf.ndim >= 2 and leaf.shape[-1] % M == 0 and leaf.shape[-1] >= M:
-            spec[-1] = "model"
-        return NamedSharding(mesh, P(*spec))
-
-    return jax.tree_util.tree_map_with_path(one, cache_shapes)
-
-
-def serve_token_shardings(layout: WorkerLayout, token_shapes: PyTree, batch_size: int) -> PyTree:
-    mesh = layout.mesh
-    dax = layout.data_axes
-    D = int(np.prod([mesh.shape[a] for a in dax]))
-    dentry = dax if len(dax) > 1 else (dax[0] if dax else None)
-
-    def one(leaf):
-        if leaf.ndim == 0:
-            return NamedSharding(mesh, P())
-        spec = [None] * leaf.ndim
-        if leaf.shape[0] == batch_size and batch_size % D == 0 and D > 1:
-            spec[0] = dentry
-        return NamedSharding(mesh, P(*spec))
-
-    return jax.tree.map(one, token_shapes)

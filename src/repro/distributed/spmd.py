@@ -24,7 +24,7 @@ Host-CPU recipe (no accelerator needed): set
 BEFORE the first jax import, build a worker mesh with
 ``launch.mesh.make_spmd_layout(8)``, and the lowered HLO contains real
 ``all-reduce`` / ``collective-permute`` ops (checked via
-``distributed.hlo_analysis``).
+``analysis.hlo``).
 
 Hierarchical layouts (``make_layout(style="hierarchical")`` /
 ``launch.mesh.make_hierarchical_layout``) run through the same wrapper: the
@@ -41,8 +41,8 @@ and the two-level replica-group structure are pinned by
 Tensor-parallel layouts (``make_hierarchical_layout(pods, data, tp)`` /
 ``make_spmd_layout(workers, tp)``) run the FULL (pod, data, model) mesh
 through the same wrapper: every parameter-shaped leaf is additionally
-model-sharded over the ``model`` axes via the same ``model_spec_tail`` rules
-the GSPMD dry-run uses, the loss executes Megatron-style — column-parallel
+model-sharded over the ``model`` axes via the ``model_spec_tail`` rules
+(``distributed.sharding``), the loss executes Megatron-style — column-parallel
 in, row-parallel out, ``psum`` over ``model`` through the backend's
 model-axis hooks (``repro.models.tp``) — and every state collective (the
 per-step ``data`` gradient sync, the boundary ``pod`` all-reduce, gossip
@@ -178,7 +178,6 @@ def build_spmd_round(
     state: PyTree,
     batches: PyTree,
     pack=None,
-    local_tree_inner=None,
 ):
     """Explicit builder: returns the jitted shard-mapped round function.
 
@@ -241,7 +240,6 @@ def build_spmd_round(
         loss_fn,
         backend,
         pack=body_pack,
-        local_tree_inner=local_tree_inner,
         tp_masks=tp_masks,
     )
     state_specs = sharding.spmd_state_specs(
@@ -271,7 +269,6 @@ def make_spmd_slowmo_round(
     loss_fn: Callable[[PyTree, PyTree], Any],
     layout: WorkerLayout,
     pack=None,
-    local_tree_inner=None,
 ):
     """Drop-in replacement for ``jax.jit(slowmo.make_slowmo_round(...))``.
 
@@ -293,13 +290,11 @@ def make_spmd_slowmo_round(
         _validate_batches(layout, batches)
         key = (jax.tree.structure(state), jax.tree.structure(batches))
         if key not in cache:
-            cache[key] = build_spmd_round(
-                cfg, loss_fn, layout, state, batches, pack, local_tree_inner
-            )
+            cache[key] = build_spmd_round(cfg, loss_fn, layout, state, batches, pack)
         return cache[key](state, batches, lr, *mask)
 
     round_fn.build = lambda state, batches: build_spmd_round(
-        cfg, loss_fn, layout, state, batches, pack, local_tree_inner
+        cfg, loss_fn, layout, state, batches, pack
     )
     return round_fn
 
@@ -310,7 +305,6 @@ def make_survivor_round(
     layout: WorkerLayout,
     survivors,
     pack=None,
-    local_tree_inner=None,
 ):
     """Rebuild the compiled round for an ordered survivor set.
 
@@ -328,7 +322,7 @@ def make_survivor_round(
     new_layout = mesh_lib.make_survivor_layout(layout, survivors)
     new_cfg = dataclasses.replace(cfg, num_workers=new_layout.num_workers)
     return new_cfg, new_layout, make_spmd_slowmo_round(
-        new_cfg, loss_fn, new_layout, pack=pack, local_tree_inner=local_tree_inner
+        new_cfg, loss_fn, new_layout, pack=pack
     )
 
 

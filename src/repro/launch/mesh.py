@@ -1,21 +1,19 @@
-"""Production mesh construction + worker-layout mapping.
+"""Mesh construction + worker-layout mapping.
 
-``make_production_mesh`` is a FUNCTION (module import never touches jax
-device state).  The dry-run launcher sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import so both meshes can be built on the CPU-only container.
+Every builder here is a FUNCTION (module import never touches jax device
+state); on a CPU-only host set
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the first jax
+import to build meshes over N placeholder devices.
 
-Worker layouts (see DESIGN.md §2):
-* ``flat``        — paper-faithful: one SlowMo worker per data-axis row
-                    (m=16 single-pod, m=32 multi-pod).
+Worker layouts:
+* ``flat``        — paper-faithful: one SlowMo worker per data-axis row.
 * ``hierarchical``— the paper's ACTUAL experimental regime (each node an
                     AllReduce DP group, SlowMo across nodes — the BMUF block
                     structure): one worker per pod; within-pod DP gradients
                     sync every step over fast ICI (the layout's
                     ``batch_axes``), SlowMo handles only the cross-pod
-                    (slow) links.  Runs both on the GSPMD dry-run path and
-                    through the shard_map execution path
-                    (``repro.distributed.spmd``).
+                    (slow) links.  Both run through the shard_map execution
+                    path (``repro.distributed.spmd``).
 """
 from __future__ import annotations
 
@@ -43,16 +41,6 @@ def device_grid(shape, what: str = "mesh") -> np.ndarray:
     if n == len(devs):
         return mesh_utils.create_device_mesh(shape, devices=devs)
     return np.asarray(devs[:n]).reshape(shape)
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return Mesh(device_grid(shape), axes)
-
-
-def make_test_mesh(shape=(2, 2, 2), axes=("pod", "data", "model")) -> Mesh:
-    return Mesh(device_grid(shape), axes)
 
 
 def make_worker_mesh(num_workers: int, axis: str = "data") -> Mesh:

@@ -126,8 +126,8 @@ def attention_chunked(q, k, v, *, causal, window, chunk=1024, scale=None):
     """Online-softmax attention in pure XLA ops: scan over kv chunks.
 
     Memory is O(Sq * chunk) instead of O(Sq * Skv) — this is the flash
-    recurrence expressed at the XLA level, used for long sequences so the
-    dry-run memory analysis reflects a production configuration.  The chunk
+    recurrence expressed at the XLA level, used for long sequences where the
+    full score matrix would not fit.  The chunk
     step is checkpointed, so that the backward pass recomputes each chunk's
     scores instead of keeping them all: O(Sq * chunk) under autodiff too.
     """

@@ -3,7 +3,7 @@
 only audio backbone with a stubbed conv-feature frontend).
 
 Layers are stacked with a leading L axis and executed with scan-over-layers
-(compact HLO; essential for the 61-layer dry-runs).  ``cfg.remat`` wraps the
+(compact HLO at any depth).  ``cfg.remat`` wraps the
 block in jax.checkpoint for training-memory control.
 
 THE one pipeline: every entry point takes an optional ``backend`` carrying

@@ -1,8 +1,8 @@
 """Shared test config.
 
 IMPORTANT: do NOT set XLA_FLAGS / host-device-count here — smoke tests and
-benchmarks must see the single real CPU device.  Dry-run tests that need many
-placeholder devices run dryrun.py in a subprocess (see test_dryrun.py).
+benchmarks must see the single real CPU device.  Tests that need many
+placeholder devices run their script in a subprocess (see test_spmd.py).
 """
 import numpy as np
 import pytest

@@ -25,10 +25,9 @@ the hierarchical execution path on a (pods=2, data=2) mesh:
   (``repro.analysis``): the census derived from the config must reconcile
   exactly against the lowered HLO's replica groups and permute pairs;
 
-* SPEC UNIFICATION — the GSPMD dry-run path (``sharding.batch_shardings``)
+* SPEC UNIFICATION — the NamedSharding view (``sharding.batch_shardings``)
   and the shard_map path (``sharding.spmd_batch_specs``) produce the same
-  batch PartitionSpecs (they used to disagree: dry-run sharded B over
-  ``data``, the mesh path replicated it).
+  batch PartitionSpecs.
 """
 import os
 import subprocess
@@ -174,7 +173,7 @@ assert set(hop_pairs) == pod_pairs, (sorted(hop_pairs), sorted(pod_pairs))
 assert any(b.op == "collective-permute" for b in ct_sgp.budgets)
 print("HIER-CP-OK gossip permutes pinned to", len(pod_pairs), "pod-level pairs")
 
-# --- one spec rule for both paths (dry-run GSPMD vs shard_map) -------------
+# --- one spec rule for both views (NamedSharding vs shard_map) -------------
 for lay in (layout, make_spmd_layout(8)):
     shapes = {"x": jax.ShapeDtypeStruct((3, lay.num_workers, B, D), jnp.float32),
               "y": jax.ShapeDtypeStruct((3, lay.num_workers, B), jnp.float32)}

@@ -2,10 +2,10 @@
 pure functions from leaf name/shape to PartitionSpec entries)."""
 import jax
 import pytest
-from jax.sharding import PartitionSpec as P
 
+from repro.analysis.hlo import collective_bytes
 from repro.configs import get_config
-from repro.distributed import hlo_analysis, sharding
+from repro.distributed import sharding
 from repro.models import build_model
 
 
@@ -87,18 +87,8 @@ class TestHloAnalysis:
   %z.done = f32[4]{0} all-gather-done(f32[4] %w)
   %t = (f32[4]{0}, f32[8]{0}) all-to-all(f32[4] %c, f32[8] %d)
 """
-        out = hlo_analysis.collective_bytes(hlo)
+        out = collective_bytes(hlo)
         assert out["all-reduce"] == 16 * 2048 * 4
         assert out["collective-permute"] == 8 * 128 * 2
         assert out["all-to-all"] == (4 + 8) * 4
         assert out["all-gather"] == 0  # -done carries no new traffic
-
-    def test_roofline_dominance(self):
-        r = hlo_analysis.Roofline(
-            flops=1e15, hbm_bytes=1e9, coll_bytes=1e9, coll_breakdown={},
-            compute_s=1e15 / hlo_analysis.PEAK_FLOPS,
-            memory_s=1e9 / hlo_analysis.HBM_BW,
-            collective_s=1e9 / hlo_analysis.ICI_BW,
-        )
-        assert r.dominant == "compute"
-        assert r.total_s == r.compute_s

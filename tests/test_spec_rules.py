@@ -97,12 +97,12 @@ class TestModelSpecTailProps:
 
 
 class TestPresetSpecUnification:
-    """Dry-run rule (slowmo_state_specs) == mesh rule (spmd_state_specs),
+    """Field-by-field rule (slowmo_state_specs) == mesh rule (spmd_state_specs),
     leaf for leaf, for every architecture preset in configs/ on a
     (pod, data, model=16) layout — the 'one rule, both paths' acceptance."""
 
     @pytest.mark.parametrize("arch", ARCH_IDS)
-    def test_dryrun_equals_mesh_specs(self, arch):
+    def test_state_specs_equal_mesh_specs(self, arch):
         cfg = get_config(arch)
         model = build_model(cfg)
         smcfg = slowmo.SlowMoConfig(num_workers=2, tau=2)

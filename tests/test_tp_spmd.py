@@ -24,9 +24,10 @@ acceptance criteria of the TP refactor on a (pods=2, data=2, model=2) mesh:
   shard — half the bytes of the TP-free packing (traffic ∝ 1/TP); gossip
   collective-permutes connect same-(data, model)-index devices across pods;
 
-* ONE RULE, BOTH PATHS — the dry-run spec rule (``slowmo_state_specs``) and
-  the mesh rule (``spmd_state_specs``) agree leaf-for-leaf on a TP state,
-  and batch specs replicate over ``model`` on both paths.
+* ONE RULE, BOTH VIEWS — the field-by-field spec rule
+  (``slowmo_state_specs``) and the mesh rule (``spmd_state_specs``) agree
+  leaf-for-leaf on a TP state, and batch specs replicate over ``model`` in
+  both views.
 """
 import os
 import subprocess

@@ -204,3 +204,40 @@ class TestHierarchicalBatchSplit:
         tc = TrainConfig(total_rounds=1, per_worker_batch=4, seq_len=D, log_every=0)
         t = Trainer(dummy_model(), smcfg, tc, dummy_sampler, layout=self.hier_layout())
         assert t.layout.effective_batch(tc.per_worker_batch) == 8
+
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "tree"])
+def test_launcher_mesh_round_matches_oracle(packed):
+    """The launcher's mesh trainer, built from arguments shaped as the chip
+    benchmark's (``--mesh host``, one worker), runs the same two rounds as
+    the array-axis oracle (``--mesh none``): this is the seam from
+    ``launch.train`` through ``Trainer`` to ``spmd.make_spmd_slowmo_round``
+    that the chip cells compile through."""
+    from repro.configs import get_config
+    from repro.core import packing
+    from repro.data import MarkovLMConfig, make_markov_sampler
+    from repro.launch import train as train_launch
+
+    argv = ["--arch", "olmo-1b", "--algo", "local_sgd+slowmo", "--tau", "2",
+            "--seq", "32", "--batch", "2", "--workers", "1"]
+    argv += ["--packed"] if packed else []
+    vocab = get_config("olmo-1b", reduced=True).vocab_size
+    sampler = make_markov_sampler(MarkovLMConfig(vocab_size=vocab, temperature=0.8), 1)
+    runs = {}
+    for mesh in ("host", "none"):
+        trainer = train_launch.build_trainer(
+            train_launch.build_parser().parse_args(argv + ["--mesh", mesh]))
+        assert (trainer.layout is not None) == (mesh == "host")
+        assert (trainer.pack is not None) == packed
+        trainer.sampler = sampler
+        state = trainer.run(state=trainer.init_state(jax.random.PRNGKey(3)), rounds=2)
+        if packed:
+            state = packing.unpack_state(trainer.pack, state)
+        runs[mesh] = ([h["loss"] for h in trainer.history],
+                      jax.tree.map(np.asarray, state.outer_params))
+    (mesh_losses, mesh_outer), (ref_losses, ref_outer) = runs["host"], runs["none"]
+    np.testing.assert_allclose(mesh_losses, ref_losses, rtol=1e-6)
+    assert jax.tree.structure(mesh_outer) == jax.tree.structure(ref_outer)
+    for a, b in zip(jax.tree.leaves(mesh_outer), jax.tree.leaves(ref_outer)):
+        assert np.max(np.abs(a - b)) <= 1e-6 * max(np.max(np.abs(b)), 1e-12)
