@@ -279,11 +279,14 @@ def main(argv=None):
         print(f"fused kernels, as traced: {tally.summary()}")
     routed = [h for h in trainer.history if "held_share" in h]
     if routed:
+        bounds = [h["rows_bound_share"] for h in routed]
         print(
             "routing over the run: "
             f"{100 * sum(h['held_share'] for h in routed) / len(routed):.2f}% of "
             "top-k assignments on held experts; largest held expert's load "
-            f"{max(h['held_load_max'] for h in routed):.3f}x the held mean"
+            f"{max(h['held_load_max'] for h in routed):.3f}x the held mean; "
+            f"expert rows bounded at {sum(bounds) / len(bounds):.4f} of the worst "
+            f"case (rounds {min(bounds):.4f}-{max(bounds):.4f})"
         )
 
 

@@ -13,7 +13,9 @@ experts 0 .. H-1 (``experts_held``; all by default): under expert
 parallelism, one chip's share, while the router still scores all E.  The
 assignments to held experts are sorted by expert into groups padded to
 whole tiles (``plan``) and run through grouped products: the ``expert_gmm``
-Pallas kernel on a TPU, XLA's ``ragged_dot`` elsewhere.  The balance loss is
+Pallas kernel on a TPU, XLA's ``ragged_dot`` elsewhere.  The plan's rows are
+bounded for the worst case; the layer runs over the smallest of a few static
+bounds (``ladder``) that holds the rows this routing filled.  The balance loss is
 Switch-style (E sum_e f_e p_e over top-1 picks, in the loss) or, with
 ``moe_aux="seq"``, DeepSeek-V2's per-sequence loss, which (as the published
 ``AddAuxiliaryLoss`` does) enters the gradient but not the loss value.
@@ -180,17 +182,114 @@ def grouped_matmul(rows, w, p: Plan):
     return jax.lax.ragged_dot(rows, w.astype(rows.dtype), p.group_rows)
 
 
-STATS = ("held_share", "held_load_max")
+def ladder(tokens: int, top_k: int, held: int, experts: int,
+           tm: int = TILE_ROWS) -> tuple:
+    """Static bounds on the rows of ``plan``, smallest first: the tiles of
+    the balanced held rows (tokens * top_k * held / experts) plus a tile an
+    expert, doubling while under the worst case M, then M.  M alone where
+    the lowest bound is more than half of M (every expert held, or few
+    tokens)."""
+    worst = (-(-tokens * min(top_k, held) // tm) + held) * tm
+    b = (-(-tokens * top_k * held // (experts * tm)) + held) * tm
+    rungs = []
+    if 2 * b <= worst:
+        while b < worst:
+            rungs.append(b)
+            b *= 2
+    return (*rungs, worst)
 
 
-def routing_stats(p: Plan, top_k: int) -> dict:
-    """The share of top-k assignments that landed on held experts, and the
-    largest held expert's assignments over the held experts' mean."""
+def rung(p: Plan, rungs: tuple) -> jax.Array:
+    """The index of the smallest of ``rungs`` that holds the active tiles."""
+    tm = p.src.shape[0] // p.tile_group.shape[0]
+    return jnp.sum(jnp.asarray(rungs[:-1]) < p.n_tiles * tm).astype(jnp.int32)
+
+
+def _experts(bound: int, x, w, p: Plan, wi, wo):
+    """The held experts' part (T, d) over the first ``bound`` of the plan's
+    rows, which hold its active tiles: dispatch, the grouped SwiGLU and
+    combine.  Every row read lies in the active tiles, so any such bound
+    gives the same result."""
+    tm = p.src.shape[0] // p.tile_group.shape[0]
+    p = p._replace(src=p.src[:bound], tile_group=p.tile_group[:bound // tm])
+    h = grouped_matmul(dispatch(x, p), wi, p)
+    gate, up = jnp.split(h, 2, axis=-1)
+    h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+    return combine(grouped_matmul(h, wo, p), w, p)
+
+
+def experts(rungs: tuple, x, w, p: Plan, wi, wo):
+    """``_experts`` over the smallest of ``rungs`` that holds the active
+    tiles; over the one bound where ``rungs`` has one."""
+    if len(rungs) == 1:
+        return _experts(rungs[0], x, w, p, wi, wo)
+    return _experts_switch(rungs, x, w, p, wi, wo)
+
+
+def _switch(rungs: tuple, fn, index, *args):
+    """``fn(rungs[index], *args)`` through ``lax.switch``.  Under ``vmap``
+    (the round maps its loss over the workers a device holds) every member
+    runs at the largest bound any of them needs: a batched index would turn
+    the switch into a select over every bound."""
+
+    @jax.custom_batching.custom_vmap
+    def switch(index, *args):
+        branches = [functools.partial(fn, b) for b in rungs]
+        return jax.lax.switch(index, branches, *args)
+
+    @switch.def_vmap
+    def _batched(axis_size, in_batched, index, *args):
+        index = jnp.max(index) if in_batched[0] else index
+        axes = jax.tree.map(lambda b: 0 if b else None, tuple(in_batched[1:]))
+        branches = [jax.vmap(functools.partial(fn, b), in_axes=axes) for b in rungs]
+        out = jax.lax.switch(index, branches, *args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return switch(index, *args)
+
+
+# One custom_vjp over the whole switch: its residuals are its inputs, the
+# same at every bound.  Differentiated as a plain ``lax.switch``, each
+# branch would also write zero-filled residuals of every other branch's
+# shapes, the worst case's among them.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _experts_switch(rungs, x, w, p, wi, wo):
+    return _switch(rungs, _experts, rung(p, rungs), x, w, p, wi, wo)
+
+
+def _experts_switch_fwd(rungs, x, w, p, wi, wo):
+    return _experts_switch(rungs, x, w, p, wi, wo), (x, w, p, wi, wo)
+
+
+def _pull(bound, x, w, p, wi, wo, g):
+    """The forward again at ``bound``, and its VJP in x, w, wi and wo."""
+    f = lambda x, w, wi, wo: _experts(bound, x, w, p, wi, wo)  # noqa: E731
+    return jax.vjp(f, x, w, wi, wo)[1](g)
+
+
+def _experts_switch_bwd(rungs, res, g):
+    x, w, p, wi, wo = res
+    dx, dw, dwi, dwo = _switch(rungs, _pull, rung(p, rungs), x, w, p, wi, wo, g)
+    return dx, dw, None, dwi, dwo
+
+
+_experts_switch.defvjp(_experts_switch_fwd, _experts_switch_bwd)
+
+
+STATS = ("held_share", "held_load_max", "rows_bound_share")
+
+
+def routing_stats(p: Plan, top_k: int, rungs: tuple) -> dict:
+    """The share of top-k assignments that landed on held experts, the
+    largest held expert's assignments over the held experts' mean, and the
+    rows of the bound the layer ran over, over the worst case's."""
     counts = p.counts.astype(jnp.float32)
     T = p.slot.shape[0]
+    bound = jnp.asarray(rungs, jnp.float32)[rung(p, rungs)]
     return {
         "held_share": jnp.sum(counts) / (T * top_k),
         "held_load_max": jnp.max(counts) / jnp.maximum(jnp.mean(counts), 1e-9),
+        "rows_bound_share": bound / rungs[-1],
     }
 
 
@@ -198,21 +297,18 @@ def moe_ffn(cfg: ModelConfig, p, x):
     """x: (B, S, d). The held routed experts' part of sum_e g_e FFN_e(x),
     dropping no token, plus the shared experts.  Returns (out, aux, stats)."""
     B, S, d = x.shape
-    dt = cfg.dtype
     with jax.named_scope("moe_route"):
         idx, gates, aux = route(cfg, p["router"], x)
         pl = plan(idx.reshape(B * S, -1), cfg.held_experts)
         w = jnp.where(pl.held, gates.reshape(B * S, -1), 0.0)
+    rungs = ladder(B * S, cfg.top_k, cfg.held_experts, cfg.n_experts)
     with jax.named_scope("moe_experts"):
-        rows = dispatch(x.reshape(B * S, d).astype(dt), pl)
-        h = grouped_matmul(rows, p["wi"], pl)
-        gate, up = jnp.split(h, 2, axis=-1)
-        h = jax.nn.silu(gate.astype(jnp.float32)).astype(dt) * up
-        out = combine(grouped_matmul(h, p["wo"], pl), w, pl).reshape(B, S, d)
+        out = experts(rungs, x.reshape(B * S, d).astype(cfg.dtype), w, pl,
+                      p["wi"], p["wo"]).reshape(B, S, d)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
             out = out + common.mlp(cfg, p["shared"], x)
-    return out, aux, routing_stats(pl, cfg.top_k)
+    return out, aux, routing_stats(pl, cfg.top_k, rungs)
 
 
 # ---------------------------------------------------------------------------
@@ -301,7 +397,8 @@ def _moe_block(cfg: ModelConfig, x, positions, bp):
 
 def backbone(cfg: ModelConfig, params, x, positions):
     """Returns (x, the balance losses summed over layers, routing stats:
-    the held share's mean and the largest held load's max over layers)."""
+    the held share's and the rows bound's means and the largest held load's
+    max over layers)."""
     if cfg.first_k_dense:
         block = functools.partial(_dense_block, cfg)
         if cfg.remat:
@@ -322,7 +419,8 @@ def backbone(cfg: ModelConfig, params, x, positions):
     x, (auxs, stats) = jax.lax.scan(body, x, params["moe_blocks"], unroll=cfg.unroll_layers)
     x = common.apply_norm(cfg, x, params["final_norm"])
     stats = {"held_share": jnp.mean(stats["held_share"]),
-             "held_load_max": jnp.max(stats["held_load_max"])}
+             "held_load_max": jnp.max(stats["held_load_max"]),
+             "rows_bound_share": jnp.mean(stats["rows_bound_share"])}
     return x, jnp.sum(auxs), stats
 
 

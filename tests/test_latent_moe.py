@@ -214,6 +214,160 @@ class TestLayers:
         assert abs(float(aux) - 1.0) < 0.15
 
 
+class TestRowBounds:
+    """The expert layer runs over the smallest of a few static row bounds
+    that holds the active tiles.  A layer of 2 x 512 tokens, top-4 of 16
+    experts, 2 held, at 128-row tiles: bounds 768, 1,536 and the worst case
+    2,304."""
+
+    B, S = 2, 512
+    LADDER = (768, 1536, 2304)
+
+    def _layer(self, router_bias):
+        cfg, arch, params = _setup(2, n_experts=16, top_k=4)
+        p = _layer(params["moe_blocks"])
+        d = cfg.d_model
+        base = jax.random.normal(jax.random.PRNGKey(20), (d,))
+        h = jax.random.normal(jax.random.PRNGKey(21), (self.B, self.S, d))
+        # tokens that share ``base`` (|base|^2 ~ d), and router columns along
+        # it, put held experts first or last for every token; the softmax
+        # keeps the others above zero
+        h = h + 3.0 * base if router_bias else h
+        router = p["router"]
+        for e, scale in router_bias.items():
+            router = router.at[:, e].set(scale * base)
+        return cfg, arch, dict(p, router=router), h
+
+    def test_ladder_from_shapes(self):
+        assert moe_mod.ladder(self.B * self.S, 4, 2, 16) == self.LADDER
+        # the benchmark cell: 2 x 4096 tokens, top-6, 8 of 64 experts held
+        assert moe_mod.ladder(8192, 6, 8, 64) == (7168, 14336, 28672, 50176)
+
+    @pytest.mark.parametrize("router_bias, want", [
+        ({}, 0),  # few: a random router sends 2/16 of the picks to the held two
+        ({0: 0.1, 1: -0.1}, 1),  # some: expert 0 takes every token, expert 1 none
+        ({0: 0.1, 1: 0.08}, 2),  # all: both take every token
+    ], ids=["few", "some", "all"])
+    def test_each_rung_matches_the_worst_case_and_reference(self, router_bias, want,
+                                                            monkeypatch):
+        cfg, arch, p, h = self._layer(router_bias)
+        idx, _, _ = moe_mod.route(cfg, p["router"], h)
+        pl = moe_mod.plan(idx.reshape(self.B * self.S, -1), 2)
+        need = int(pl.n_tiles) * moe_mod.TILE_ROWS
+        assert min(i for i, b in enumerate(self.LADDER) if b >= need) == want
+        assert int(moe_mod.rung(pl, self.LADDER)) == want
+
+        def run():
+            def f(p, h):
+                out, _, stats = moe_mod.moe_ffn(cfg, p, h)
+                return jnp.sum(jnp.sin(out)), (out, stats)
+
+            with jax.default_matmul_precision("highest"):
+                (_, (out, stats)), grads = jax.jit(
+                    jax.value_and_grad(f, (0, 1), has_aux=True))(p, h)
+            return out, stats, grads
+
+        out, stats, grads = run()
+        assert float(stats["rows_bound_share"]) == pytest.approx(
+            self.LADDER[want] / self.LADDER[-1], rel=1e-6)
+        ladder = moe_mod.ladder
+        monkeypatch.setattr(moe_mod, "ladder", lambda *a: ladder(*a)[-1:])
+        worst, worst_stats, worst_grads = run()
+        assert float(worst_stats["rows_bound_share"]) == 1.0
+        _close(out, worst, 1e-6)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(worst_grads)):
+            _close(a, b, 1e-6)
+
+        def ref_f(p, h):
+            out = jnp.stack([ref.experts(arch, p, h[b])[0] for b in range(self.B)])
+            return jnp.sum(jnp.sin(out)), out
+
+        with jax.default_matmul_precision("highest"):
+            (_, want_out), want_grads = jax.jit(
+                jax.value_and_grad(ref_f, (0, 1), has_aux=True))(p, h)
+        _close(out, want_out, RTOL)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+            _close(a, b, GRAD_RTOL)
+
+    def test_no_worst_case_residuals_under_remat(self):
+        """The gradient of a checkpointed layer writes no zero-filled buffer
+        of the worst case's rows: a plain ``lax.switch`` would fill every
+        other bound's residuals in each branch."""
+        cfg, _, p, h = self._layer({})
+        worst = self.LADDER[-1]
+
+        def loss(p, h):
+            return jnp.sum(moe_mod.moe_ffn(cfg, p, h)[0])
+
+        jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(loss)))(p, h)
+        fills, conds = [], 0
+        for eqn in _eqns(jaxpr.jaxpr):
+            conds += eqn.primitive.name == "cond"
+            if eqn.primitive.name != "broadcast_in_dim":
+                continue
+            shape = eqn.outvars[0].aval.shape
+            if len(shape) >= 2 and shape[0] == worst and eqn.invars[0].aval.shape == ():
+                fills.append(shape)
+        assert conds >= 2  # the forward's and the backward's switch
+        assert not fills, fills
+
+    def test_workers_mapped_share_one_switch(self):
+        """Under ``vmap`` (the round maps the loss over a device's workers)
+        the bound stays a switch, at the largest bound any worker needs: a
+        select would run every bound."""
+        cfg, _, p, h = self._layer({})
+        _, _, p_all, h_all = self._layer({0: 0.1, 1: 0.08})
+        ps = jax.tree.map(lambda a, b: jnp.stack([a, b]), p, p_all)
+        hs = jnp.stack([h, h_all])
+
+        def loss(p, h):
+            out, _, stats = moe_mod.moe_ffn(cfg, p, h)
+            return jnp.sum(jnp.sin(out)), (out, stats["rows_bound_share"])
+
+        def conds(f, *args):
+            return sum(e.primitive.name == "cond" for e in _eqns(jax.make_jaxpr(f)(*args).jaxpr))
+
+        one = jax.value_and_grad(jax.checkpoint(loss), has_aux=True)
+        step = jax.vmap(one)
+        assert conds(step, ps, hs) == conds(one, p, h) >= 2
+        with jax.default_matmul_precision("highest"):
+            (_, (out, share)), grads = jax.jit(step)(ps, hs)
+            (_, (out0, share0)), grads0 = jax.jit(jax.value_and_grad(loss, has_aux=True))(p, h)
+        # each worker counts its own bound; both ran at the largest
+        assert [float(v) for v in share] == [pytest.approx(768 / 2304), 1.0]
+        assert float(share0) == pytest.approx(768 / 2304)
+        _close(out[0], out0, 1e-6)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads0)):
+            _close(a[0], b, 1e-6)
+
+    @pytest.mark.parametrize("tokens, held", [(1024, 16), (4, 8), (64, 8)],
+                             ids=["all-held", "decode-4", "decode-64"])
+    def test_one_rung_runs_the_worst_case(self, tokens, held):
+        """Every expert held, or a decode step's few tokens at the published
+        layer: one bound, the worst case, and no switch."""
+        E, k = (16, 4) if held == 16 else (64, 6)
+        assert len(moe_mod.ladder(tokens, k, held, E)) == 1
+        cfg, _, params = _setup(held, n_experts=E, top_k=k)
+        p = _layer(params["moe_blocks"])
+        h = jax.random.normal(jax.random.PRNGKey(22), (1, tokens, cfg.d_model))
+        jaxpr = jax.make_jaxpr(lambda p, h: moe_mod.moe_ffn(cfg, p, h))(p, h)
+        assert not [e for e in _eqns(jaxpr.jaxpr) if e.primitive.name == "cond"]
+        _, _, stats = moe_mod.moe_ffn(cfg, p, h)
+        assert float(stats["rows_bound_share"]) == 1.0
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+                elif hasattr(sub, "jaxpr") and hasattr(sub.jaxpr, "eqns"):
+                    yield from _eqns(sub.jaxpr)
+
+
 class TestWholeModel:
     @pytest.mark.parametrize("held", [None, 2])
     def test_loss_and_grads_match_reference(self, held):

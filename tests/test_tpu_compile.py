@@ -312,6 +312,40 @@ def test_expert_gmm_compiles_forward_and_backward(one_chip, no_persistent_cache)
     assert _kernel_names(c) == {"expert_gmm"}
 
 
+def test_expert_layer_row_bounds_compile(one_chip, no_persistent_cache, monkeypatch):
+    """The expert layer over bounds of 768, 1,536 and 2,304 rows (2 x 512
+    tokens, top-4 of 16 experts, 2 held): the loss and gradient of the
+    checkpointed layer, mapped over the one worker a chip holds as the round
+    maps it, compile with one conditional for the forward and one for the
+    backward, each bound's products through ``expert_gmm``."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import build_model
+    from repro.models import moe
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("deepseek-v2-lite", reduced=True).replace(
+        n_experts=16, top_k=4, experts_held=2, dtype=jnp.bfloat16)
+    assert moe.ladder(1024, 4, 2, 16) == (768, 1536, 2304)
+    p = jax.eval_shape(lambda k: jax.tree.map(lambda x: x[0], build_model(cfg).init(k)
+                                              ["moe_blocks"]), jax.random.PRNGKey(0))
+
+    def loss(p, h):
+        return jnp.sum(moe.moe_ffn(cfg, p, h)[0].astype(jnp.float32))
+
+    def s(x):
+        return jax.ShapeDtypeStruct((1,) + x.shape, x.dtype, sharding=one_chip)
+
+    c = _compile(jax.vmap(jax.value_and_grad(jax.checkpoint(loss), (0, 1))), jax.tree.map(s, p),
+                 s(jax.ShapeDtypeStruct((2, 512, cfg.d_model), jnp.float32)))
+    hlo = c.as_text()
+    assert sum(" conditional(" in line for line in hlo.splitlines()) == 2
+    assert _kernel_names(c) == {"expert_gmm"}
+    # at each bound: the forward's two products; the backward's two again
+    # (combine's gradient reads the down product's rows) and four
+    assert _kernel_calls(c) == 3 * 8
+
+
 def test_moe_round_compiles_with_the_expert_kernel(topo, no_persistent_cache, monkeypatch):
     """A packed round of the REDUCED deepseek-v2-lite (latent attention,
     dropless MoE) on one described chip runs the expert layer through
